@@ -6,7 +6,9 @@ instruction: on that hardware conv2D beat FullyConnected by 25x in RPS, so GEMM
 was lowered onto strided conv2D (§7.1.2).
 
 Here the same table is (re-)measured on the actual backend by
-``benchmarks/table1_ops.py`` and cached; ``best_gemm_lowering`` consumes it.
+``benchmarks/table1_ops.py`` and cached per ``device_kind`` (a table measured
+on one device kind is never read on another); ``best_gemm_lowering``
+consumes it.
 On TPU v5e the ordering *inverts* (matmul is the MXU-native op; conv lowers to
 matmul with layout overhead) — the framework discovers that from data rather
 than assuming it, exactly as the paper did.
@@ -17,14 +19,14 @@ from __future__ import annotations
 import json
 import os
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
 
 _CACHE_ENV = "REPRO_INSTR_TABLE"
 _DEFAULT_CACHE = os.path.join(os.path.dirname(__file__), "_instr_table.json")
-_table: Optional[Dict[str, Dict[str, float]]] = None
+_tables: Dict[str, Dict[str, Dict[str, float]]] = {}   # device_kind -> table
 
 
 def measure_op(fn: Callable, *args, iters: int = 30) -> Dict[str, float]:
@@ -95,21 +97,26 @@ def _gemm_conv(a, b):
 
 
 def get_table(refresh: bool = False) -> Dict[str, Dict[str, float]]:
-    global _table
+    """The measured table of the default device's kind: from memory, else
+    from the cache file's entry for that kind, else measured now and added
+    to the file."""
+    kind = jax.devices()[0].device_kind
+    if kind in _tables and not refresh:
+        return _tables[kind]
     path = os.environ.get(_CACHE_ENV, _DEFAULT_CACHE)
-    if _table is not None and not refresh:
-        return _table
-    if not refresh and os.path.exists(path):
+    stored: Dict[str, Dict] = {}
+    if os.path.exists(path):
         with open(path) as f:
-            _table = json.load(f)
-        return _table
-    _table = build_table()
-    try:
-        with open(path, "w") as f:
-            json.dump(_table, f, indent=1)
-    except OSError:
-        pass
-    return _table
+            stored = json.load(f)
+    if kind not in stored or refresh:
+        stored[kind] = build_table()
+        try:
+            with open(path, "w") as f:
+                json.dump(stored, f, indent=1)
+        except OSError:
+            pass
+    _tables[kind] = stored[kind]
+    return _tables[kind]
 
 
 def best_gemm_lowering() -> str:

@@ -11,42 +11,52 @@ Roles:
   model-dim  -> "model"         TP: attention heads / d_ff / vocab / experts (EP)
   sequence   -> "data"          SP for long-context KV caches (decode cells)
 
-The mesh is carried in a module-level context so model code never takes a mesh
-parameter; tests and launchers call ``set_mesh``/``use_mesh``.
+The mesh is carried in a context so model code never takes a mesh parameter.
+``set_mesh`` sets the process-wide default; ``use_mesh`` overrides it for the
+calling thread only, so several engines in one process (one per device) can
+each trace and run their steps under their own mesh, from any thread.
 """
 
 from __future__ import annotations
 
 import contextlib
+import threading
 from typing import Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-_MESH: Optional[Mesh] = None
+_DEFAULT_MESH: Optional[Mesh] = None
+_LOCAL = threading.local()
 
 
 def set_mesh(mesh: Optional[Mesh]) -> None:
-    global _MESH
-    _MESH = mesh
+    global _DEFAULT_MESH
+    _DEFAULT_MESH = mesh
+
+
+def active_mesh() -> Optional[Mesh]:
+    """The calling thread's ``use_mesh`` mesh, else the process default."""
+    mesh = getattr(_LOCAL, "mesh", None)
+    return mesh if mesh is not None else _DEFAULT_MESH
 
 
 def current_mesh() -> Mesh:
-    if _MESH is None:
+    mesh = active_mesh()
+    if mesh is None:
         raise RuntimeError("no mesh set — call distributed.set_mesh(...) or use_mesh(...)")
-    return _MESH
+    return mesh
 
 
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh):
-    global _MESH
-    prev = _MESH
-    _MESH = mesh
+    prev = getattr(_LOCAL, "mesh", None)
+    _LOCAL.mesh = mesh
     try:
         with mesh:
             yield mesh
     finally:
-        _MESH = prev
+        _LOCAL.mesh = prev
 
 
 def axis_names() -> Tuple[str, ...]:

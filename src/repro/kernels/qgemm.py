@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 # MXU-aligned defaults. bk=512 amortizes the accumulator epilogue; VMEM use:
 # bm*bk + bk*bn (int8) + bm*bn*4 (int32 acc) = 128*512*2 + 128*128*4 ≈ 196 KiB.
@@ -86,7 +85,7 @@ def qgemm(
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],  # int32 accumulator tile
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(a_q, b_q, sb.reshape(1, N))
@@ -141,7 +140,7 @@ def qgemm_tile_scales(
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((t, t), jnp.float32)],  # f32 accumulator tile
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(a_q, b_q, sa, sb)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 BM = 256  # rows per block; W is kept whole (stencils are row-contiguous)
 
@@ -65,6 +64,6 @@ def stencil3x3(
         out_specs=pl.BlockSpec((bm, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Hp, W), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
     )(top, mid, bot, w.astype(jnp.float32))
     return out[:H]

@@ -145,8 +145,6 @@ def _compile_once(cfg, shape, donate_ok=True):
     lowered = jax.jit(fn, donate_argnums=donate if donate_ok else ()).lower(*args)
     compiled = lowered.compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):      # jax 0.4.x wraps it per-device
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     return compiled, cost, collective_bytes(hlo), len(hlo)
 

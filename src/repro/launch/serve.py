@@ -64,6 +64,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import tensorizer as tz
 from repro.distributed import sharding as shd
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.models import init_model
 from repro.serving.api import serve_api
@@ -73,7 +74,8 @@ from repro.serving.metrics import (format_memory_stats, format_router_stats,
                                    format_transport_stats)
 from repro.serving.router import Router, RouterConfig, parse_disaggregate
 from repro.serving.sampling import SamplingParams
-from repro.serving.transport import SubprocessTransport, build_model_spec
+from repro.serving.transport import (WORKER_ON_TPU, SubprocessTransport,
+                                     build_model_spec)
 
 
 def _quant_predicate(path, leaf):
@@ -248,8 +250,7 @@ def _spawn_fleet(args, ecfg):
     in-process path). A boot failure reaps the partial fleet — no orphans."""
     spec = build_model_spec(
         args.arch, smoke=args.smoke, quantize=args.quantize, seed=0,
-        draft_arch=args.draft_config if args.speculative else None,
-        model_parallel=args.model_parallel)
+        draft_arch=args.draft_config if args.speculative else None)
     fleet = []
     try:
         for _ in range(args.hosts):
@@ -401,7 +402,13 @@ def main(argv=None) -> int:
                  "engine handles token-input dense/moe (contiguous or paged "
                  "KV) and ssm/hybrid (recurrent-state) archs; encdec/vlm "
                  "serving is a ROADMAP item")
-    mesh = make_smoke_mesh(args.model_parallel)
+    if args.host_procs and jax.default_backend() == "tpu":
+        ap.error(WORKER_ON_TPU)
+    enable_compile_cache()
+    # an engine runs on one device (device 0); the Router puts host i on
+    # device i mod n. --model-parallel widens the mesh's model axis.
+    mesh = make_smoke_mesh(args.model_parallel,
+                           devices=jax.devices()[:args.model_parallel])
 
     with shd.use_mesh(mesh):
         params = init_model(cfg, jax.random.PRNGKey(0))

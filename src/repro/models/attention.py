@@ -673,9 +673,9 @@ def paged_decode_attention(
 
     ``use_kernel`` routes the attention contraction through the Pallas
     kernel (kernels/paged_attention.py) — truly block-granular HBM traffic,
-    online softmax (float-equivalent, not bit-exact; float-KV only, the
-    int8 path keeps the jnp contraction). Off-TPU the kernel runs in
-    interpret mode, which is how CPU CI exercises it.
+    online softmax (float-equivalent, not bit-exact; float KV only — an
+    int8 KV cache raises). Off-TPU the kernel runs in interpret mode, which
+    is how CPU CI exercises it.
 
     Returns ``(out, pool_k, pool_v)`` (+ scale pools on the int8 path)."""
     B = x.shape[0]
@@ -700,13 +700,16 @@ def paged_decode_attention(
         pool_k = pool_k.at[phys, off].set(k_new[:, 0].astype(pool_k.dtype))
         pool_v = pool_v.at[phys, off].set(v_new[:, 0].astype(pool_v.dtype))
 
-    if use_kernel and not int8_cache:
+    if use_kernel:
+        if int8_cache:
+            raise ValueError("the Pallas paged-attention kernel reads a "
+                             "float KV pool; int8 KV has no kernel path")
         from repro.kernels.paged_attention import (
             paged_decode_attention as _pallas_paged)
         o = _pallas_paged(
             q[:, 0].astype(jnp.float32), pool_k, pool_v,
             tables.astype(jnp.int32), index.astype(jnp.int32),
-            interpret=jax.default_backend() != "tpu")
+            interpret=shd.current_mesh().devices.flat[0].platform != "tpu")
         o = o[:, None].astype(x.dtype)        # (B, 1, H, hd)
     else:
         # per-layer transient gather of this layer's table-addressed rows,

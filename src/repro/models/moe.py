@@ -35,13 +35,6 @@ from repro.configs.base import ArchConfig
 from repro.distributed import sharding as shd
 from repro.models import layers as L
 
-if hasattr(jax, "shard_map"):                       # jax >= 0.6
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:                                               # jax 0.4.x container
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
 def init_moe(key, cfg: ArchConfig, d: int) -> Dict:
     ks = jax.random.split(key, 5)
     E, F = cfg.n_experts, cfg.d_ff
@@ -207,9 +200,9 @@ def apply_moe(p: Dict, x: jax.Array, cfg: ArchConfig, *,
         P("model" if has_model else None, None, None),
     )
     out_specs = (P(batch_entry, None, None), P(batch_entry))
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: False},
+        check_vma=False,
     )(x, active, router, wi, wg, wo)
 
     if cfg.n_shared_experts:

@@ -29,9 +29,9 @@ Threading model: the Engine/Router are NOT thread-safe (host-side slot
 state, OPQ dispatch), so ONE driver thread owns the backend and runs the
 serve loop (step + harvest); HTTP handler threads (ThreadingHTTPServer)
 talk to it exclusively through a command queue and receive tokens through
-per-request stream queues. The driver thread enters the jax mesh context
-itself (``mesh=`` argument) because jax's active-mesh state is
-thread-local — the creating thread's ``with mesh:`` does not reach here.
+per-request stream queues. Each Engine enters its own device and mesh on
+whatever thread calls it, so the serve-loop thread needs no mesh;
+``mesh=`` additionally enters one around the loop.
 
 Requests and responses carry token IDS, not text: tokenization is the
 client's business (the repo has no tokenizer dependency), which also keeps
@@ -431,9 +431,8 @@ class ApiServer:
 def serve_api(target, *, port: int = 0, host: str = "127.0.0.1",
               mesh=None) -> ApiServer:
     """Boot the HTTP serve API over an Engine or Router. Returns the
-    running :class:`ApiServer`; pass the jax mesh the backend's programs
-    were built under — the driver thread must enter it itself (jax's
-    active-mesh context is thread-local)."""
+    running :class:`ApiServer`. ``mesh`` (optional) is entered on the
+    serve-loop thread around the loop."""
     backend = _Backend(target)
     loop = _ServeLoop(backend, mesh=mesh)
     loop.start()

@@ -56,6 +56,7 @@ serving is a ROADMAP item.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import enum
 import functools
@@ -64,9 +65,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 
 from repro.configs.base import ArchConfig
 from repro.core.opq import OPQ, Buffer
+from repro.distributed import sharding as shd
+from repro.launch.mesh import device_mesh
 from repro.models import steps as ST
 from repro.serving.metrics import EngineMetrics, RequestMetrics, now
 from repro.serving.sampling import (
@@ -199,8 +203,9 @@ class EngineConfig:
         With ``paged_native`` (added PR 4): route the attention contraction
         through the Pallas paged-attention kernel
         (kernels/paged_attention.py — scalar-prefetch block-table addressing
-        + online softmax, block-sized VMEM working set). Float-KV only; runs
-        in interpret mode off-TPU, which is how CPU CI exercises it.
+        + online softmax, block-sized VMEM working set). Float KV only: an
+        int8 KV cache is refused at construction. Runs in interpret mode
+        off-TPU, which is how CPU CI exercises it.
     prefill_chunk
         Dense families only (added PR 4): chunked prefill width W. Buckets
         wider than W admit through a ``lax.scan`` of W-token chunks — peak
@@ -264,26 +269,6 @@ class EngineConfig:
     draft: Optional[ArchConfig] = None
 
 
-def _spec_round_donate() -> bool:
-    """Whether the speculative-round steps (verify, dense draft decode) may
-    donate their cache argument. Not on CPU: jax 0.4.37's XLA:CPU runtime
-    can deserialize an executable from the persistent compilation cache
-    whose completion events fire BEFORE its donated in-place writes land —
-    ``block_until_ready`` on its outputs returns early, so the rollback
-    scrub dispatched right after a verify races the verify's own tail
-    writes and intermittently loses the rejected window cells (stale draft
-    K/V where pristine was written; reproducible only with a warm
-    ``.jax_cache``, never with freshly compiled executables). Whether an
-    entry was deserialized is not observable here, so CPU skips donation
-    for exactly the two steps whose freshly written cells the round
-    overwrites microseconds later. Plain decode/prefill keep donation on
-    every backend: nothing ever overwrites a cell they just wrote before
-    the next data-dependent executable, so a late write is unobservable.
-    TPU keeps the in-place verify — donation is what holds peak cache
-    memory to one pool during the wide forward."""
-    return jax.default_backend() != "cpu"
-
-
 @functools.lru_cache(maxsize=None)
 def _jitted_steps(cfg: ArchConfig, kind: str, max_seq_len: int = 0,
                   native: bool = False, kernel: bool = False, chunk: int = 0,
@@ -332,8 +317,7 @@ def _jitted_steps(cfg: ArchConfig, kind: str, max_seq_len: int = 0,
     if spec_k:
         verify_fn = (ST.make_paged_verify_step(cfg, spec_k + 1) if native
                      else ST.make_verify_step(cfg, spec_k + 1))
-        verify = (jax.jit(verify_fn, donate_argnums=(1,))
-                  if _spec_round_donate() else jax.jit(verify_fn))
+        verify = jax.jit(verify_fn, donate_argnums=(1,))
     return prefill, prefill_chunked, prefill_suffix, decode, verify
 
 
@@ -352,7 +336,7 @@ def _jitted_draft_steps(cfg: ArchConfig, kind: str, max_seq_len: int = 0,
         prefill = jax.jit(ST.make_prefill_with_cache_step(cfg))
     decode_fn = ST.make_decode_step(cfg)
     decode = (jax.jit(decode_fn, donate_argnums=(1,))
-              if donate and _spec_round_donate() else jax.jit(decode_fn))
+              if donate else jax.jit(decode_fn))
     return prefill, decode
 
 
@@ -378,6 +362,16 @@ class QueueFull(Exception):
     """Raised by submit(strict=True) when admission control rejects."""
 
 
+def _placed(method):
+    """Run an Engine method under the engine's device and mesh (see
+    :meth:`Engine.placed`)."""
+    @functools.wraps(method)
+    def wrapper(self, *args, **kwargs):
+        with self.placed():
+            return method(self, *args, **kwargs)
+    return wrapper
+
+
 class Engine:
     """See module docstring. Typical use::
 
@@ -387,17 +381,33 @@ class Engine:
     """
 
     def __init__(self, cfg: ArchConfig, params, engine_cfg: EngineConfig = None,
-                 *, opq: Optional[OPQ] = None, draft_params=None):
+                 *, opq: Optional[OPQ] = None, draft_params=None,
+                 mesh: Optional[Mesh] = None):
         if (cfg.family not in ("dense", "moe") + RECURRENT_FAMILIES
                 or cfg.input_mode != "tokens"):
             raise ValueError(
                 f"serving engine supports token-input dense/moe/ssm/hybrid "
                 f"archs, got family={cfg.family} input_mode={cfg.input_mode}")
         self.cfg = cfg
-        self.params = params
         self.ecfg = engine_cfg or EngineConfig()
+        # one device per engine: its params, cache and every step it traces
+        # or runs live there (the ambient one-device mesh, else device 0)
+        if mesh is None:
+            mesh = shd.active_mesh() or device_mesh(jax.devices()[0])
+        if mesh.size != 1:
+            raise ValueError(
+                f"an Engine runs on exactly one device, got a mesh of "
+                f"{mesh.size} ({dict(mesh.shape)}); serve several devices "
+                f"with one Engine per device behind the Router")
+        self.mesh = mesh
+        self.device = mesh.devices.flat[0]
         if self.ecfg.paged_kernel and not self.ecfg.paged_native:
             raise ValueError("paged_kernel requires paged_native=True")
+        if self.ecfg.paged_kernel and cfg.kv_cache_dtype == "int8":
+            raise ValueError(
+                "paged_kernel attends over a float KV pool; the int8 KV cache "
+                "(kv_cache_dtype='int8') has no kernel path — drop "
+                "paged_kernel or use a float KV cache")
         if self.ecfg.paged_native and self.ecfg.cache_backend != "paged":
             raise ValueError(
                 f"paged_native requires cache_backend='paged', got "
@@ -493,38 +503,8 @@ class Engine:
                     f"{self.ecfg.block_size} to divide every prefill bucket "
                     f"(got {bad})")
         self.scheduler = Scheduler(self.ecfg.max_slots, buckets)
-        self.store: SlotStore = make_store(
-            cfg, self.ecfg.max_slots, self.ecfg.max_seq_len,
-            backend=self.ecfg.cache_backend,
-            block_size=self.ecfg.block_size, n_blocks=self.ecfg.n_blocks,
-            native=self.ecfg.paged_native,
-            prefix_cache=self.ecfg.prefix_cache)
-        (self._prefill, self._prefill_chunked, self._prefill_suffix,
-         self._decode, self._verify) = _jitted_steps(
-            cfg, self.store.kind,
-            self.ecfg.max_seq_len if self.store.kind == "recurrent" else 0,
-            native=self.ecfg.paged_native, kernel=self.ecfg.paged_kernel,
-            chunk=chunk or 0,
-            prefix_chunk=self.ecfg.block_size if self.ecfg.prefix_cache else 0,
-            spec_k=self.ecfg.spec_k if self.ecfg.speculative else 0)
-        self._owns_opq = opq is None and self.ecfg.use_opq
-        self.opq = (OPQ() if self._owns_opq else opq) if self.ecfg.use_opq else None
-        self._params_buf = Buffer(params, name="params")
-        # the draft model is a SECOND program over the same slot geometry:
-        # its own params buffer, its own slot-synced store (contiguous for
-        # dense drafts, per-slot state rows for recurrent ones), admitted /
-        # rolled back / reset in lockstep with the target's slots
-        self.draft_store: Optional[SlotStore] = None
-        if self.ecfg.speculative:
-            dcfg = self.ecfg.draft
-            self.draft_store = make_store(dcfg, self.ecfg.max_slots,
-                                          self.ecfg.max_seq_len)
-            self._draft_recurrent = self.draft_store.kind == "recurrent"
-            self._draft_prefill, self._draft_decode = _jitted_draft_steps(
-                dcfg, self.draft_store.kind,
-                self.ecfg.max_seq_len if self._draft_recurrent else 0,
-                donate=not self._draft_recurrent)
-            self._draft_params_buf = Buffer(draft_params, name="draft-params")
+        with self.placed():
+            self._build_device_state(cfg, params, draft_params, chunk, opq)
         self._req_ids = itertools.count()
         # host-side token-presence bitmap per slot (prompt + generated): the
         # repetition penalty's input, maintained through admit/emit/retire so
@@ -541,6 +521,55 @@ class Engine:
         self._prefix_contrib: Dict[int, Tuple[int, int, int]] = {}
         self.completed: List[Request] = []
 
+    def _build_device_state(self, cfg, params, draft_params, chunk, opq):
+        """Everything that lives on the engine's device: params, the slot
+        store, the compiled steps, the OPQ lanes, and the draft model's."""
+        self.params = jax.device_put(params, self.device)
+        self.store: SlotStore = make_store(
+            cfg, self.ecfg.max_slots, self.ecfg.max_seq_len,
+            backend=self.ecfg.cache_backend,
+            block_size=self.ecfg.block_size, n_blocks=self.ecfg.n_blocks,
+            native=self.ecfg.paged_native,
+            prefix_cache=self.ecfg.prefix_cache)
+        (self._prefill, self._prefill_chunked, self._prefill_suffix,
+         self._decode, self._verify) = _jitted_steps(
+            cfg, self.store.kind,
+            self.ecfg.max_seq_len if self.store.kind == "recurrent" else 0,
+            native=self.ecfg.paged_native, kernel=self.ecfg.paged_kernel,
+            chunk=chunk or 0,
+            prefix_chunk=self.ecfg.block_size if self.ecfg.prefix_cache else 0,
+            spec_k=self.ecfg.spec_k if self.ecfg.speculative else 0)
+        self._owns_opq = opq is None and self.ecfg.use_opq
+        self.opq = ((OPQ([self.device]) if self._owns_opq else opq)
+                    if self.ecfg.use_opq else None)
+        self._params_buf = self._resident(self.params, "params")
+        # the draft model is a SECOND program over the same slot geometry:
+        # its own params buffer, its own slot-synced store (contiguous for
+        # dense drafts, per-slot state rows for recurrent ones), admitted /
+        # rolled back / reset in lockstep with the target's slots
+        self.draft_store: Optional[SlotStore] = None
+        if self.ecfg.speculative:
+            dcfg = self.ecfg.draft
+            self.draft_store = make_store(dcfg, self.ecfg.max_slots,
+                                          self.ecfg.max_seq_len)
+            self._draft_recurrent = self.draft_store.kind == "recurrent"
+            self._draft_prefill, self._draft_decode = _jitted_draft_steps(
+                dcfg, self.draft_store.kind,
+                self.ecfg.max_seq_len if self._draft_recurrent else 0,
+                donate=not self._draft_recurrent)
+            self._draft_params_buf = self._resident(
+                jax.device_put(draft_params, self.device), "draft-params")
+
+    @contextlib.contextmanager
+    def placed(self):
+        """Enter this engine's device (default placement) and mesh on the
+        calling thread. Both are thread-local, so every entry point that
+        touches the device — and every OPQ worker running one of its
+        steps — enters them itself; engines on different devices in one
+        process never see each other's placement."""
+        with jax.default_device(self.device), shd.use_mesh(self.mesh):
+            yield
+
     @property
     def kv(self) -> SlotStore:
         """Back-compat alias from the KVSlotManager era — the slot store."""
@@ -549,12 +578,7 @@ class Engine:
     # ------------------------------------------------------------ OPQ bridge
 
     def _resident(self, tree, name: str) -> Buffer:
-        leaves = jax.tree.leaves(tree)
-        try:
-            dev = next(iter(leaves[0].devices()))
-            return Buffer.resident(tree, dev, name=name)
-        except (AttributeError, IndexError, StopIteration):
-            return Buffer(tree, name=name)
+        return Buffer.resident(tree, self.device, name=name)
 
     def _dispatch(self, fn, *bufs: Buffer, flags: str = ""):
         """Run one instruction to completion (decode path)."""
@@ -567,9 +591,12 @@ class Engine:
         one round on the lanes before a single wait. Untracked: the engine
         consumes each result itself, so nothing is retained for sync() and
         the task registry stays empty over an unbounded serving run."""
+        def run(*args):
+            with self.placed():
+                return fn(*args)
         if self.opq is None:
-            return _Ready(fn(*(b.data for b in bufs)))
-        return self.opq.invoke_operator(fn, *bufs, flags=flags, track=False)
+            return _Ready(run(*(b.data for b in bufs)))
+        return self.opq.invoke_operator(run, *bufs, flags=flags, track=False)
 
     # ------------------------------------------------------------- admission
 
@@ -1043,6 +1070,7 @@ class Engine:
         self.metrics.evicted += len(out)
         return out
 
+    @_placed
     def preempt(self, req_id: int) -> Request:
         """Remove an in-flight request at a step boundary: retire its slot,
         scrub its cache rows, and return it with the tokens it generated so
@@ -1076,6 +1104,7 @@ class Engine:
     # bits, so the continued stream is bit-identical to never having moved —
     # unlike re-prefill continuation, which remains the fallback oracle.
 
+    @_placed
     def extract_seeded(self, req_id: int) -> Tuple[Request, Dict]:
         """Preempt an in-flight request AND export its slot's cache blocks
         as a serialized payload (store.export_blocks): the request's wire
@@ -1108,6 +1137,7 @@ class Engine:
                 return req, payload
         raise KeyError(f"request {req_id} is not in flight on this engine")
 
+    @_placed
     def release_exported(self, payload_id: str) -> bool:
         """Ack a shipped payload: release the export ledger's hold on its
         blocks (refcount-correct — trie-cached blocks stay cached, private
@@ -1115,6 +1145,7 @@ class Engine:
         already acked."""
         return self.store.release_exported(payload_id)
 
+    @_placed
     def submit_seeded(self, prompt: Sequence[int], max_new_tokens: int,
                       tokens: Sequence[int], payload: Dict,
                       *, sampling: Optional[SamplingParams] = None,
@@ -1195,6 +1226,7 @@ class Engine:
         self.metrics.imported_blocks += payload["n_blocks"]
         return req
 
+    @_placed
     def step(self) -> None:
         """One engine iteration: join waiting requests into free slots, then
         one batched decode step for whatever is in flight."""
@@ -1239,6 +1271,7 @@ class Engine:
 
     # ------------------------------------------------------- non-generative
 
+    @_placed
     def embed(self, prompt: Sequence[int]) -> Dict[str, np.ndarray]:
         """Non-generative forward for the serve API (embeddings /
         classification): one bucketed dispatch returning the prompt's

@@ -53,8 +53,11 @@ import tempfile
 from pathlib import Path
 from typing import Dict, List, Optional, Protocol, Sequence
 
+import jax
 import numpy as np
 
+from repro.launch.cache import ENV as CACHE_ENV, compile_cache_dir
+from repro.launch.mesh import device_mesh
 from repro.serving.engine import (
     Engine, EngineConfig, QueueFull, Request, RequestState,
 )
@@ -215,15 +218,13 @@ def engine_cfg_from_wire(d: Dict, draft_cfg=None) -> EngineConfig:
 
 def build_model_spec(arch: str, *, smoke: bool = True, quantize: str = "off",
                      overrides: Optional[Dict] = None, seed: int = 0,
-                     draft_arch: Optional[str] = None,
-                     model_parallel: int = 1) -> Dict:
+                     draft_arch: Optional[str] = None) -> Dict:
     """The deterministic model recipe a worker rebuilds its params from:
     registry arch name, smoke scaling, ArchConfig field overrides, the
     Tensorizer quantize mode, and the init PRNG seed. Same spec + same
     backend => bit-identical weights in every process."""
     spec = {"arch": arch, "smoke": bool(smoke), "quantize": quantize,
-            "overrides": dict(overrides or {}), "seed": int(seed),
-            "model_parallel": int(model_parallel)}
+            "overrides": dict(overrides or {}), "seed": int(seed)}
     if draft_arch:
         spec["draft"] = {"arch": draft_arch, "smoke": bool(smoke),
                          "seed": int(seed)}
@@ -576,16 +577,25 @@ class InProcessTransport:
 def build_inproc_fleet(cfg, params, engine_cfg: Optional[EngineConfig] = None,
                        n_hosts: int = 1, *,
                        draft_params=None) -> List[InProcessTransport]:
-    """N in-process hosts over shared params — compiled steps are shared
-    across them via the engine's _jitted_steps cache, so N hosts costs N
-    caches, not N XLA compiles. The default Router fleet."""
+    """N in-process hosts, host ``i`` on device ``i mod n_devices`` — one
+    chip per host on a multi-chip machine, all on the one device otherwise.
+    Hosts on the same device share compiled steps via the engine's
+    _jitted_steps cache. The default Router fleet."""
+    devices = jax.devices()
     return [
         InProcessTransport(EngineHost(
-            Engine(cfg, params, engine_cfg, draft_params=draft_params)))
-        for _ in range(n_hosts)]
+            Engine(cfg, params, engine_cfg, draft_params=draft_params,
+                   mesh=device_mesh(devices[i % len(devices)]))))
+        for i in range(n_hosts)]
 
 
 # ------------------------------------------------------------- subprocess
+
+WORKER_ON_TPU = (
+    "subprocess host workers cannot run on a TPU machine: a chip belongs to "
+    "one process, and this one already holds it. Serve several chips from "
+    "one process instead — the in-process Router puts one host on each "
+    "chip (--hosts N without --host-procs).")
 
 # ops safe to retry after a timeout: read-only predicates and cursor-based
 # reads. submit/evict/preempt mutate — a lost reply leaves the mutation's
@@ -620,6 +630,8 @@ class SubprocessTransport:
                  connect_timeout_s: float = 300.0,
                  request_timeout_s: float = 300.0,
                  retries: int = 2):
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(WORKER_ON_TPU)
         self.model_spec = dict(model_spec)
         self.ecfg = engine_cfg or EngineConfig()
         self.request_timeout_s = request_timeout_s
@@ -658,20 +670,14 @@ class SubprocessTransport:
 
     @staticmethod
     def _worker_env() -> Dict[str, str]:
-        import jax
         import repro
         env = dict(os.environ)
         src_dir = str(Path(repro.__file__).resolve().parents[1])
         existing = env.get("PYTHONPATH")
         env["PYTHONPATH"] = (src_dir + os.pathsep + existing
                              if existing else src_dir)
-        # share the parent's persistent compilation cache so sibling workers
-        # load executables the first one compiled
-        cache_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
-        if cache_dir:
-            env.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
-            env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                           "0.5")
+        # sibling workers share one persistent compilation cache
+        env[CACHE_ENV] = compile_cache_dir()
         return env
 
     # --------------------------------------------------------------- rpc

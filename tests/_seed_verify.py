@@ -1,7 +1,7 @@
 """Preemption-seed verification harness.
 
 The transport/router test suite pins its reference streams to specific RNG
-seeds (historically 21/22/13 in tests/test_transport.py), hand-picked so
+seeds (6/4/0 in tests/test_transport.py), hand-picked so
 that a re-prefill CONTINUATION — re-admitting ``prompt + harvested_tokens``
 with the remaining budget, exactly what the router does after a drain, a
 host loss, or a failed block ship — happens to be bit-identical to the
@@ -151,6 +151,7 @@ def main(argv=None) -> int:
 
     from repro.configs import get_config
     from repro.distributed import sharding as shd
+    from repro.launch.mesh import make_mesh
     from repro.models import init_model
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -165,7 +166,7 @@ def main(argv=None) -> int:
                          "(4 layers, d_model 256) instead of plain smoke")
     args = ap.parse_args(argv)
 
-    shd.set_mesh(jax.make_mesh((1,), ("data",)))
+    shd.set_mesh(make_mesh((1,), ("data",)))
     cfg = get_config(args.arch).smoke()
     if args.big:
         cfg = cfg.replace(n_layers=4, d_model=256, n_heads=8, n_kv=4,

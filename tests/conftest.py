@@ -1,23 +1,20 @@
-import os
-
-import jax
 import pytest
 
 from repro.distributed import sharding as shd
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 
 # NOTE: no XLA_FLAGS here on purpose — tests run on the real single CPU
 # device; only launch/dryrun.py forces 512 host devices (assignment step 0).
 
 # Persistent XLA compilation cache: the fast tier is compile-bound on CPU, so
 # repeated runs (local dev, the tier-1 gate) skip recompilation entirely.
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(os.path.dirname(__file__), os.pardir, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+enable_compile_cache()
 
 
 @pytest.fixture(scope="session")
 def mesh():
-    m = jax.make_mesh((1,), ("data",))
+    m = make_mesh((1,), ("data",))
     shd.set_mesh(m)
     with m:
         yield m

@@ -135,21 +135,29 @@ def test_continuation_sweep_has_teeth():
 
 @pytest.mark.slow
 def test_pinned_transport_seeds_verified():
-    """The seeds tests/test_transport.py pins (21/22/13) were historically
-    hand-picked so their preemption tests' particular cut points happened
-    to stitch cleanly. Verify the greedy streams of those (config, seed)
-    pairs through the harness at a spread of cut points — replacing the
-    folklore with a sweep any future re-pin must pass."""
+    """The prompt seeds tests/test_transport.py pins (6/4/0) are those whose
+    greedy streams stitch cleanly at EVERY continuation cut point: its
+    drain, SIGKILL and flaky-channel tests re-admit those streams at
+    timing-dependent W. Verify each through the harness — the sweep any
+    future re-pin must pass."""
     big = CFG.replace(n_layers=4, d_model=256, n_heads=8, n_kv=4,
                       d_ff=1024, vocab=512, head_dim=32)
     bparams = init_model(big, jax.random.PRNGKey(0))
-    for seed, plen, gen in ((21, 7, 96), (22, 6, 96), (13, 6, 96)):
-        prompt = np.random.default_rng(seed).integers(
-            0, big.vocab, (plen,), dtype=np.int32)
-        sv.assert_clean_continuations(
-            big, bparams, prompt, gen,
-            ecfg_kw=dict(max_slots=2, max_seq_len=128),
-            cut_points=(1, 2, 3, gen // 2, gen - 2))
+    # (seed, prompt lengths, greedy (prompt index, gen) pairs, KV dtypes)
+    pinned = [
+        (6, (6, 9, 4, 7), ((1, 96), (3, 6)), ("bfloat16", "int8")),
+        (4, (6, 5, 7, 4), ((1, 80), (3, 80)), ("bfloat16",)),
+        (0, (6, 5), ((0, 96), (1, 24)), ("bfloat16",)),
+    ]
+    for seed, lens, streams, kv_dtypes in pinned:
+        rng = np.random.default_rng(seed)
+        prompts = [rng.integers(0, big.vocab, (n,), dtype=np.int32)
+                   for n in lens]
+        for kv in kv_dtypes:
+            for i, gen in streams:
+                sv.assert_clean_continuations(
+                    big.replace(kv_cache_dtype=kv), bparams, prompts[i],
+                    gen, ecfg_kw=dict(max_slots=2, max_seq_len=128))
 
 
 # ============================================================== bit-identity

@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import load_checkpoint, save_checkpoint, latest_step
 from repro.configs import get_config
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.ft import plan_elastic_mesh
 from repro.models import init_model, steps, param_specs
 from repro.optim import adamw_init
@@ -31,7 +32,7 @@ batch = {"tokens": jnp.arange(8*16, dtype=jnp.int32).reshape(8,16) % cfg.vocab,
          "labels": (jnp.arange(8*16, dtype=jnp.int32).reshape(8,16)+1) % cfg.vocab}
 
 # ---- phase 1: train 2 steps on a (4, 2) mesh, checkpoint ----
-mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+mesh1 = make_mesh((4, 2), ("data", "model"))
 with shd.use_mesh(mesh1):
     params = init_model(cfg, jax.random.PRNGKey(0))
     opt = adamw_init(params)
@@ -45,7 +46,7 @@ with shd.use_mesh(mesh1):
 plan = plan_elastic_mesh(n_surviving_hosts=1, chips_per_host=4,
                          model_parallel=2, old_data_parallel=4, global_batch=8)
 assert plan["mesh_shape"] == (2, 2), plan
-mesh2 = jax.make_mesh(plan["mesh_shape"], plan["axis_names"])
+mesh2 = make_mesh(plan["mesh_shape"], plan["axis_names"])
 with shd.use_mesh(mesh2):
     like_p = jax.eval_shape(lambda k: init_model(cfg, k), jax.random.PRNGKey(0))
     like_o = jax.eval_shape(adamw_init, like_p)

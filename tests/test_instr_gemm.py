@@ -80,3 +80,26 @@ class TestGemmLowerings:
         out = np.asarray(gemm.tpu_gemm(jnp.asarray(a), jnp.asarray(b)))
         exact = a @ b
         assert np.abs(out - exact).max() / np.abs(exact).max() < 0.02
+
+
+def test_instr_table_is_keyed_by_device_kind(tmp_path, monkeypatch):
+    """A cost table measured on one device kind is never read on another:
+    the file keeps one table per kind, and a missing kind is measured."""
+    import json
+
+    import jax
+
+    from repro.core import instr_select as IS
+
+    path = tmp_path / "instr_table.json"
+    other = {"gemm_conv2d": {"results_per_s": 1e12}}
+    path.write_text(json.dumps({"some other kind": other}))
+    here = {"gemm_fully_connected": {"results_per_s": 1.0}}
+    monkeypatch.setenv(IS._CACHE_ENV, str(path))
+    monkeypatch.setattr(IS, "_tables", {})
+    monkeypatch.setattr(IS, "build_table", lambda: here)
+    assert IS.get_table() == here
+    assert IS.best_gemm_lowering() == "fully_connected"
+    stored = json.loads(path.read_text())
+    assert stored == {"some other kind": other,
+                      jax.devices()[0].device_kind: here}

@@ -22,7 +22,9 @@
                   slot reset (no state leaks across leases)
 """
 
+import types
 import warnings
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -724,6 +726,41 @@ def test_paged_native_requires_paged_backend(params):
                                          paged_kernel=True))
     with pytest.raises(ValueError, match="paged"):
         make_store(CFG, 2, 32, backend="contiguous", native=True)
+
+
+def test_paged_kernel_refuses_int8_kv(params):
+    """The Pallas kernel reads a float pool: an int8 KV cache is refused at
+    construction instead of quietly falling back to the jnp contraction."""
+    with pytest.raises(ValueError, match="int8"):
+        Engine(CFG.replace(kv_cache_dtype="int8"), params,
+               EngineConfig(max_slots=2, max_seq_len=32,
+                            cache_backend="paged", paged_native=True,
+                            paged_kernel=True))
+
+
+def test_engine_runs_on_one_device(params, mesh):
+    """An Engine takes the ambient one-device mesh and pins its params and
+    cache there; a mesh of several devices is refused."""
+    eng = Engine(CFG, params, EngineConfig(max_slots=2, max_seq_len=32))
+    assert eng.device == mesh.devices.flat[0]
+    placed = {d for leaf in jax.tree.leaves((eng.params, eng.store.cache))
+              for d in leaf.devices()}
+    assert placed == {eng.device}
+    assert eng.opq.devices == [eng.device]
+    eng.close()
+    wide = types.SimpleNamespace(size=2, shape={"data": 2})
+    with pytest.raises(ValueError, match="one device"):
+        Engine(CFG, params, EngineConfig(max_slots=2, max_seq_len=32),
+               mesh=wide)
+
+
+def test_compile_cache_dir_follows_the_environment(monkeypatch):
+    from repro.launch.cache import CHECKOUT_CACHE, ENV, compile_cache_dir
+    monkeypatch.setenv(ENV, "/elsewhere/cache")
+    assert compile_cache_dir() == "/elsewhere/cache"
+    monkeypatch.delenv(ENV)
+    assert compile_cache_dir() == str(CHECKOUT_CACHE)
+    assert CHECKOUT_CACHE.parent == Path(__file__).resolve().parents[1]
 
 
 @pytest.mark.parametrize("family,kv_dtype", [

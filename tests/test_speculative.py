@@ -575,15 +575,3 @@ def test_spec_config_validation(params):
                EngineConfig(**base, cache_backend="paged", paged_native=True,
                             paged_kernel=True, **_spec_kw()),
                draft_params=params)
-
-
-def test_spec_round_donation_gated_off_cpu():
-    """Regression pin for the jax 0.4.37 XLA:CPU donation race: an executable
-    deserialized from the persistent compilation cache can signal completion
-    before its donated in-place writes land, so the rollback scrub dispatched
-    right after a verify races the verify's own tail writes. The gate must
-    disable donation on CPU (correctness) and keep it everywhere else (the
-    no-copy verify round is the perf point). If a jax upgrade fixes the
-    runtime, this test is the reminder to re-measure before re-enabling."""
-    from repro.serving.engine import _spec_round_donate
-    assert _spec_round_donate() == (jax.default_backend() != "cpu")

@@ -52,6 +52,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.models import init_model, steps
 from repro.optim import adamw_init
 
@@ -60,7 +61,7 @@ batch = {"tokens": jnp.arange(8*16, dtype=jnp.int32).reshape(8,16) % cfg.vocab,
          "labels": (jnp.arange(8*16, dtype=jnp.int32).reshape(8,16)+1) % cfg.vocab}
 losses = []
 for shape, names in [((1,), ("data",)), ((2, 4), ("data", "model"))]:
-    mesh = jax.make_mesh(shape, names)
+    mesh = make_mesh(shape, names)
     with shd.use_mesh(mesh):
         params = init_model(cfg, jax.random.PRNGKey(0))
         opt = adamw_init(params)

@@ -296,7 +296,7 @@ def test_lost_host_streams_recover_bit_identically(params):
 # inside the envelope — the same pre-existing engine hole tracked in
 # ROADMAP, amplified. Sampled streams still run in every fleet, pinned to
 # the surviving host, and must match the sequential reference exactly.
-# Each test draws prompts from its own fixed rng (seeds 21/22/13 below) so
+# Each test draws prompts from its own fixed rng (seeds 6/4/0 below) so
 # the token streams are identical regardless of which other tests ran
 # first. Those seeds are NOT folklore: tests/_seed_verify.py sweeps the
 # greedy continuation space of each pinned (config, seed) pair — cutting
@@ -350,7 +350,7 @@ def test_subprocess_fleet_bit_identical_to_sequential(kv_dtype):
     single in-process engine serving the same requests sequentially."""
     cfg = BIG_CFG.replace(kv_cache_dtype=kv_dtype)
     params = init_model(cfg, jax.random.PRNGKey(0))   # same seed as workers
-    prompts = _prompts([6, 9, 4, 7], cfg=cfg, rng=np.random.default_rng(21))
+    prompts = _prompts([6, 9, 4, 7], cfg=cfg, rng=np.random.default_rng(6))
     # long second generation: the drain must land while it is mid-decode on
     # a free-running worker, so the handoff really crosses the wire
     gens = [48, 96, 8, 6]
@@ -403,7 +403,7 @@ def test_sigkill_mid_decode_recovers_bit_identically():
     the sequential reference exactly — the un-harvested tokens died with
     the process and were regenerated, never double-emitted."""
     params = init_model(BIG_CFG, jax.random.PRNGKey(0))
-    prompts = _prompts([6, 5, 7, 4], rng=np.random.default_rng(22))
+    prompts = _prompts([6, 5, 7, 4], rng=np.random.default_rng(4))
     # long generations so the SIGKILL lands while the victim is mid-decode
     gens = [96, 80, 96, 80]
     samplings = [_sampling(i) for i in range(4)]
@@ -497,7 +497,7 @@ def test_lost_host_never_double_emits_over_flaky_transport():
     token appears twice even though the dead host had generated (and we had
     harvested) a prefix of it."""
     params = init_model(BIG_CFG, jax.random.PRNGKey(0))
-    prompts = _prompts([6, 5], rng=np.random.default_rng(13))
+    prompts = _prompts([6, 5], rng=np.random.default_rng(0))
     gens = [96, 24]
     # both greedy: req 0 is the preempted stream, and req 1 could land on
     # the same host as req 0 under load ties, so it must survive a re-prefill
