@@ -6,10 +6,10 @@
 Sweeps prompt length x admission batch and times how long it takes to seed a
 leased slot's KV cache, two ways:
 
-  * fused  — the engine's admission path: ONE bucketed prefill forward
-    returning first-token + per-layer K/V, ONE batched donated scatter into
-    the slot rows (models/serve.py prefill_with_cache + serving/kv.py
-    write_slots). One dispatch per bucket, flat in prompt length.
+  * fused  — the engine's admission path: ONE bucketed prefill forward per
+    request returning first-token + per-layer K/V, ONE donated scatter into
+    its slot rows (models/serve.py prefill_with_cache + serving/kv.py
+    write_slots). One dispatch per request, flat in prompt length.
   * replay — the PR-1 baseline, reconstructed here (it no longer exists in
     src/): replay the prompt token-by-token through the B=1 decode step and
     copy the region into the slot row. L dispatches, linear in prompt length.
@@ -36,7 +36,6 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_smoke_mesh
 from repro.models import init_model
 from repro.models import serve as SV
 from repro.models import steps as ST
@@ -70,7 +69,7 @@ def fused_seed_cell(cfg, params, *, prompt_len: int, batch: int, max_seq: int,
     def admit_once():
         for p in prompts:
             eng.submit(p, max_new_tokens=2, strict=True)
-        eng._admit()                      # one prefill + one batched write
+        eng._admit()                      # one prefill + one write each
         jax.block_until_ready(eng.kv.cache["k"])
         for slot in list(eng.scheduler.active):
             eng._retire(slot)
@@ -128,7 +127,7 @@ def main(argv=None) -> int:
     max_seq = max(lengths) + 8
 
     cfg = get_config(args.arch).smoke().replace(kv_cache_dtype=args.kv_dtype)
-    mesh = make_smoke_mesh(1)
+    mesh = shd.device_mesh(jax.devices()[0])
     cells = []
     with shd.use_mesh(mesh):
         params = init_model(cfg, jax.random.PRNGKey(0))
@@ -151,7 +150,7 @@ def main(argv=None) -> int:
                 }
                 cells.append(cell)
                 emit(f"admission_L{L}_b{batch}_fused", 1e6 * fused_s,
-                     f"1 forward/bucket speedup={cell['speedup']:.1f}x")
+                     f"1 forward/request speedup={cell['speedup']:.1f}x")
                 emit(f"admission_L{L}_b{batch}_replay", 1e6 * replay_s,
                      f"{L} B=1 decodes/req (deleted baseline)")
 

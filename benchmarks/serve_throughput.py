@@ -93,7 +93,6 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import tensorizer as tz
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_smoke_mesh
 from repro.launch.serve import _quant_predicate
 from repro.models import init_model
 from repro.serving.engine import Engine, EngineConfig
@@ -748,7 +747,7 @@ def sampling_report(cfg, params, *, slots: int, prompt_len: int, gen: int,
     eng.close()
 
     eng = Engine(cfg, params, EngineConfig(**base))
-    srv = serve_api(eng, port=0, mesh=shd.current_mesh())
+    srv = serve_api(eng, port=0)
     try:
         conn = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=60)
         t0 = time.perf_counter()
@@ -1366,8 +1365,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     cfg = get_config(args.arch).smoke().replace(quantize=args.quantize)
-    mesh = make_smoke_mesh(1)
-    with shd.use_mesh(mesh):
+    with shd.use_mesh(shd.device_mesh(jax.devices()[0])):
         params = init_model(cfg, jax.random.PRNGKey(0))
         if args.quantize == "serve":
             params = tz.quantize_params(params, predicate=_quant_predicate)

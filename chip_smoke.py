@@ -8,23 +8,20 @@ Serves ``tinyllama-1.1b`` (22 layers, d_model 2048, random weights from a
 seed) through the entry points a user calls:
 
   A  HTTP front, float weights: ``serve_api`` over a paged, block-native
-     Engine (8 slots, max_seq_len 1024). Eight completions (prompts of 128
-     and 512 tokens, 32 new tokens each, one of them SSE-streamed) must each
-     return 32 tokens with ``finish_reason == "length"``, equal to a direct
-     Engine run of the same requests one at a time.
+     Engine (8 slots, max_seq_len 1024). Eight concurrent completions
+     (prompts of 128 and 512 tokens, 32 new tokens each, one of them
+     SSE-streamed) must each return 32 tokens with ``finish_reason ==
+     "length"``, equal to a direct Engine run of the same requests one at a
+     time.
   B  int8 weights + the Pallas paged-attention kernel: the kernel's output
      against the jnp gather reference at these widths, then the same traffic
-     straight on the Engine, equal to one at a time, with the OPQ flag audit
-     showing no replay decodes.
+     straight on the Engine, all submitted at once, equal to one at a time,
+     with the OPQ flag audit showing one prefill per request and no replay
+     decodes.
   --four-chips  a Router with four in-process hosts, one per chip: 16 greedy
-     requests over 4 sessions with a mid-run ``drain(0)``; each host's params
-     and cache live on its own chip, and the stitched streams equal one
-     engine on chip 0 serving the same segments.
-
-Arrivals are staggered: each request is admitted alone and then decodes
-together with the earlier ones. On a TPU a prefill run together with other
-requests is not bit-identical to the same prefill run alone, so a request
-admitted in a group is not compared with one admitted by itself.
+     requests over 4 sessions, submitted at once, with a mid-run
+     ``drain(0)``; each host's params and cache live on its own chip, and the
+     stitched streams equal one engine on chip 0 serving the same segments.
 
 It refuses to run without a TPU (exit 1, no result line). Each phase prints
 its facts on its own line: device kind and count, jax/jaxlib/libtpu
@@ -61,8 +58,8 @@ from repro.configs import get_config  # noqa: E402
 from repro.configs.base import ArchConfig  # noqa: E402
 from repro.core import tensorizer as tz  # noqa: E402
 from repro.kernels.paged_attention import paged_decode_attention  # noqa: E402
-from repro.launch.cache import enable_compile_cache  # noqa: E402
-from repro.launch.mesh import device_mesh  # noqa: E402
+from repro.core.compile_cache import enable_compile_cache  # noqa: E402
+from repro.distributed.sharding import device_mesh  # noqa: E402
 from repro.launch.serve import _quant_predicate  # noqa: E402
 from repro.models import init_model  # noqa: E402
 from repro.serving import Engine, EngineConfig, serve_api  # noqa: E402
@@ -183,30 +180,29 @@ def _check_finished(tokens: List[int], reason, gen: int, what: str) -> None:
                              f"{reason!r}; want {gen}, 'length'")
 
 
-def staggered(engine: Engine, prompts, gen: int) -> List[List[int]]:
-    """Submit one request per admission: each prefill runs alone while the
-    earlier requests decode together in the shared batch."""
-    reqs = []
-    for p in prompts:
-        reqs.append(engine.submit(p, gen, strict=True))
-        admitted = engine.metrics.prefill_batches + 1
-        while engine.metrics.prefill_batches < admitted:
-            engine.step()
+def concurrent(engine: Engine, prompts, gen: int) -> List[List[int]]:
+    """Submit every request at once: they are admitted together and decode
+    in one shared batch."""
+    reqs = [engine.submit(p, gen, strict=True) for p in prompts]
     engine.run_until_complete()
     for r in reqs:
         _check_finished(r.tokens, r.finish_reason, gen, f"request {r.id}")
     return [list(r.tokens) for r in reqs]
 
 
-def audit_flags(engine: Engine, prompts) -> Dict[str, int]:
-    """OPQ flag audit: one prefill instruction per bucket batch, one decode
-    instruction per decode step, nothing else (no replay decodes)."""
+def audit_flags(engine: Engine, prompts, rounds: int) -> Dict[str, int]:
+    """OPQ flag audit after ``rounds`` passes of ``prompts``: one prefill
+    instruction per admitted request, one decode instruction per decode
+    step, nothing else (no replay decodes)."""
     stats = engine.stats()
     flags = stats["opq"]["flags"]
-    buckets = {f"prefill/{bucket_for(len(p), engine.scheduler.buckets)}"
-               for p in prompts}
-    if set(flags) != buckets | {"decode"}:
-        raise AssertionError(f"unexpected OPQ instructions: {flags}")
+    want = {}
+    for p in prompts:
+        flag = f"prefill/{bucket_for(len(p), engine.scheduler.buckets)}"
+        want[flag] = want.get(flag, 0) + rounds
+    if {f: c for f, c in flags.items() if f != "decode"} != want:
+        raise AssertionError(f"OPQ instructions {flags}, want prefills "
+                             f"{want} and decode")
     if flags["decode"] != stats["decode_steps"]:
         raise AssertionError(
             f"{flags['decode']} decode instructions for "
@@ -252,19 +248,9 @@ def _complete(port: int, prompt, gen: int, stream: bool):
         conn.close()
 
 
-def _prefill_batches(port: int) -> int:
-    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
-    try:
-        conn.request("GET", "/v1/stats")
-        return json.loads(conn.getresponse().read())["prefill_batches"]
-    finally:
-        conn.close()
-
-
 def http_completions(engine: Engine, prompts, gen: int):
-    """The prompts through ``serve_api``, staggered: each request is sent
-    once ``/v1/stats`` shows the one before it admitted, so every prefill
-    runs alone and the streams decode together. Request 0 streams (SSE)."""
+    """The prompts through ``serve_api``, one client thread each, all sent
+    at once. Request 0 streams (SSE)."""
     srv = serve_api(engine, port=0)
     results: List = [None] * len(prompts)
     errors: List[BaseException] = []
@@ -278,13 +264,8 @@ def http_completions(engine: Engine, prompts, gen: int):
     threads = [threading.Thread(target=client, args=(i,))
                for i in range(len(prompts))]
     try:
-        base = _prefill_batches(srv.port)
-        deadline = time.monotonic() + 900
-        for i, t in enumerate(threads):
+        for t in threads:
             t.start()
-            while (_prefill_batches(srv.port) < base + i + 1 and not errors
-                   and time.monotonic() < deadline):
-                time.sleep(0.01)
         for t in threads:
             t.join(timeout=1200)
     finally:
@@ -312,7 +293,7 @@ def assert_same(got: List[List[int]], want: List[List[int]],
 
 
 def phase_http(cfg: ArchConfig, params, traffic: Traffic = TRAFFIC) -> Dict:
-    """Phase A: float weights behind the HTTP front, staggered arrivals
+    """Phase A: float weights behind the HTTP front, concurrent arrivals
     against the same requests run directly one at a time."""
     prompts = make_prompts(cfg, traffic)
     engine = Engine(cfg, params, engine_config(traffic))
@@ -321,7 +302,7 @@ def phase_http(cfg: ArchConfig, params, traffic: Traffic = TRAFFIC) -> Dict:
         served = http_completions(engine, prompts, traffic.gen)
         assert_same(served, direct, "HTTP tokens differ from the "
                     "one-at-a-time engine run")
-        flags = audit_flags(engine, prompts)
+        flags = audit_flags(engine, prompts, rounds=2)
     finally:
         engine.close()
     return {"requests": len(prompts), "tokens": sum(map(len, served)),
@@ -401,9 +382,9 @@ def phase_kernel(cfg: ArchConfig, params, traffic: Traffic = TRAFFIC) -> Dict:
     engine = Engine(cfg, params, engine_config(traffic, paged_kernel=True))
     try:
         direct = one_at_a_time(engine, prompts, traffic.gen)
-        assert_same(staggered(engine, prompts, traffic.gen), direct,
-                    "staggered kernel-path tokens differ from one at a time")
-        flags = audit_flags(engine, prompts)
+        assert_same(concurrent(engine, prompts, traffic.gen), direct,
+                    "concurrent kernel-path tokens differ from one at a time")
+        flags = audit_flags(engine, prompts, rounds=2)
     finally:
         engine.close()
     return {"kernel_max_abs_diff": err, "requests": len(prompts),
@@ -412,16 +393,14 @@ def phase_kernel(cfg: ArchConfig, params, traffic: Traffic = TRAFFIC) -> Dict:
 
 def phase_router(cfg: ArchConfig, params, devices,
                  traffic: Traffic = ROUTER_TRAFFIC) -> Dict:
-    """Four in-process Router hosts, host i on devices[i]. Requests arrive
-    staggered over four sessions; once host 0's last one is admitted,
-    ``drain(0)`` hands that stream off mid-decode (host 0's older streams
-    are short enough to finish in place). Every stream must equal one
-    engine on devices[0]: an undisturbed stream its one-at-a-time run, the
-    handed-off stream its first W tokens followed by the continuation
-    (prompt + those W tokens, re-admitted) on that engine. Whether the
-    handed-off stream also equals its undrained run is reported, not
-    asserted: re-prefilled positions are not bit-identical to decoded ones
-    on a TPU."""
+    """Four in-process Router hosts, host i on devices[i]. All requests are
+    submitted at once over four sessions; two tokens in, ``drain(0)`` hands
+    host 0's streams off mid-decode. Every stream must equal one engine on
+    devices[0]: an undisturbed stream its one-at-a-time run, a handed-off
+    stream its first W tokens followed by the continuation (prompt + those
+    W tokens, admitted fresh) on that engine. Whether a handed-off stream
+    also equals its undrained run is reported, not asserted: re-prefilled
+    positions are not bit-identical to decoded ones (ROADMAP Design 10)."""
     prompts = make_prompts(cfg, traffic, n=ROUTER_REQUESTS)
     ref = Engine(cfg, params, engine_config(traffic),
                  mesh=device_mesh(devices[0]))
@@ -431,30 +410,18 @@ def phase_router(cfg: ArchConfig, params, devices,
                         RouterConfig(n_hosts=len(devices),
                                      handoff_threshold=ROUTER_GEN - 4))
         try:
-            reqs, cuts = [], {}
-            deadline = time.monotonic() + 900
-
-            def step_until(done, what):
-                while not done():
-                    router.step()
-                    if time.monotonic() > deadline:
-                        raise AssertionError(f"{what} never happened")
-
-            for i, p in enumerate(prompts):
-                r = router.submit(p, ROUTER_GEN,
+            reqs = [router.submit(p, ROUTER_GEN,
                                   session=str(i % ROUTER_SESSIONS),
                                   strict=True)
-                reqs.append(r)
-                step_until(lambda: r.tokens, f"admission of request {i}")
-                if i == ROUTER_REQUESTS - ROUTER_SESSIONS:
-                    # host 0's last request: hand it off, and let its
-                    # continuation be admitted alone too
-                    router.drain(0)
-                    cuts = {j: len(q.tokens) for j, q in enumerate(reqs)
-                            if len(q.hosts) > 1}
-                    step_until(lambda: all(len(reqs[j].tokens) > w
-                                           for j, w in cuts.items()),
-                               "admission of the handed-off stream")
+                    for i, p in enumerate(prompts)]
+            deadline = time.monotonic() + 900
+            while not all(len(r.tokens) >= 2 for r in reqs):
+                router.step()
+                if time.monotonic() > deadline:
+                    raise AssertionError("requests were never admitted")
+            router.drain(0)
+            cuts = {i: len(r.tokens) for i, r in enumerate(reqs)
+                    if len(r.hosts) > 1}
             router.run_until_complete()
             for i, engine in enumerate(router.engines):
                 placed = {d for leaf in jax.tree.leaves(
@@ -463,6 +430,9 @@ def phase_router(cfg: ArchConfig, params, devices,
                 if engine.device != devices[i] or placed != {devices[i]}:
                     raise AssertionError(f"host {i} lives on {placed}, want "
                                          f"{devices[i]}")
+            for r in reqs:
+                _check_finished(r.tokens, r.finish_reason, ROUTER_GEN,
+                                f"router request {r.id}")
             got = [list(r.tokens) for r in reqs]
         finally:
             router.close()
@@ -522,7 +492,8 @@ def main(argv=None) -> int:
             facts = phase_http(cfg, params)
             report("A http", clock, time.perf_counter() - t0, devices[:1],
                    f"{facts['requests']} requests, {facts['tokens']} tokens, "
-                   f"HTTP (staggered) == one at a time, flags {facts['flags']}")
+                   f"HTTP (concurrent) == one at a time, flags "
+                   f"{facts['flags']}")
         qcfg, qparams = quantize(cfg, params)
         del params
         gc.collect()
@@ -532,7 +503,7 @@ def main(argv=None) -> int:
             report("B int8+kernel", clock, time.perf_counter() - t0,
                    devices[:1],
                    f"kernel max abs diff {facts['kernel_max_abs_diff']} "
-                   f"(tol {KERNEL_TOL}), staggered == one at a time, "
+                   f"(tol {KERNEL_TOL}), concurrent == one at a time, "
                    f"flags {facts['flags']}")
     d = jax.devices()[0]
     print(json.dumps({"ok": True, "device": {

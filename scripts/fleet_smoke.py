@@ -39,7 +39,6 @@ from pathlib import Path
 import numpy as np
 
 from repro.configs import get_config
-from repro.launch.mesh import make_smoke_mesh
 from repro.serving import Router, RouterConfig, serve_api
 from repro.serving.engine import EngineConfig
 from repro.serving.router import parse_disaggregate
@@ -111,7 +110,7 @@ def main() -> int:
     router = Router(transports=fleet,
                     router_cfg=RouterConfig(
                         handoff_threshold=2 if roles else 0, roles=roles))
-    srv = serve_api(router, port=0, mesh=make_smoke_mesh(1))
+    srv = serve_api(router, port=0)
     results = [None] * REQUESTS
 
     def post(i):

@@ -22,7 +22,6 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_smoke_mesh
 from repro.models import init_model
 from repro.serving import Engine, EngineConfig, serve_api
 
@@ -43,8 +42,7 @@ def _request(port, method, path, body=None):
 
 def main() -> int:
     cfg = get_config("tinyllama-1.1b").smoke()
-    mesh = make_smoke_mesh(1)
-    with shd.use_mesh(mesh):
+    with shd.use_mesh(shd.device_mesh(jax.devices()[0])):
         params = init_model(cfg, jax.random.PRNGKey(0))
         rng = np.random.default_rng(5)
         prompt = [int(t) for t in rng.integers(0, cfg.vocab, (8,))]
@@ -58,7 +56,7 @@ def main() -> int:
         ref_eng.close()
 
         eng = Engine(cfg, params, EngineConfig(max_slots=2, max_seq_len=64))
-        srv = serve_api(eng, port=0, mesh=mesh)
+        srv = serve_api(eng, port=0)
         try:
             status, body = _request(srv.port, "GET", "/healthz")
             assert status == 200 and body == {"ok": True}, body

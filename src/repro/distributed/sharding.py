@@ -1,7 +1,7 @@
 """Sharding rules: logical roles -> mesh axes, for any of the three meshes.
 
-Meshes (launch/mesh.py):
-  smoke       (1,)            ("data",)                     CPU tests
+Meshes (built with :func:`make_mesh`; the pod meshes in launch/mesh.py):
+  device      (1,)            ("data",)                     one serving Engine
   single-pod  (16, 16)        ("data", "model")             256 chips
   multi-pod   (2, 16, 16)     ("pod", "data", "model")      512 chips
 
@@ -10,6 +10,11 @@ Roles:
                                 inter-pod DCI all-reduce — GSPMD derives it)
   model-dim  -> "model"         TP: attention heads / d_ff / vocab / experts (EP)
   sequence   -> "data"          SP for long-context KV caches (decode cells)
+
+Every mesh is built by :func:`make_mesh` with ``AxisType.Auto`` axes: the
+model code places values with ``lax.with_sharding_constraint``
+(:func:`with_sharding`), which jax only accepts on Auto axes outside an
+explicit-mesh context, while ``jax.make_mesh`` defaults to Explicit axes.
 
 The mesh is carried in a context so model code never takes a mesh parameter.
 ``set_mesh`` sets the process-wide default; ``use_mesh`` overrides it for the
@@ -24,10 +29,26 @@ import threading
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _DEFAULT_MESH: Optional[Mesh] = None
 _LOCAL = threading.local()
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types (over ``devices`` when given)."""
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=types)
+    return Mesh(np.asarray(devices, dtype=object).reshape(shape), axes,
+                axis_types=types)
+
+
+def device_mesh(device) -> Mesh:
+    """The one-device ``("data",)`` mesh a serving Engine runs under."""
+    return make_mesh((1,), ("data",), devices=[device])
 
 
 def set_mesh(mesh: Optional[Mesh]) -> None:

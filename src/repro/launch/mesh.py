@@ -1,13 +1,8 @@
-"""Mesh builders — every mesh in the repo is made here.
+"""The launchers' meshes, built with ``sharding.make_mesh`` (Auto axes).
 
 Importing this module never touches jax device state. The dry-run launcher
 sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import so the host platform exposes enough placeholder devices.
-
-All axes are ``AxisType.Auto``: the model code places values with
-``lax.with_sharding_constraint`` (distributed/sharding.py), which jax only
-accepts on Auto axes outside an explicit-mesh context, while
-``jax.make_mesh`` defaults to Explicit axes.
 
 Mesh geometry (TPU v5e pods, 256 chips each):
   single-pod  (16, 16)        ("data", "model")
@@ -20,26 +15,10 @@ between pods) — GSPMD derives the two-level schedule from the sharding.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
-
 import jax
-import numpy as np
-from jax.sharding import AxisType, Mesh
+from jax.sharding import Mesh
 
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
-              devices: Optional[Sequence] = None) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types (over ``devices`` when given)."""
-    types = (AxisType.Auto,) * len(axes)
-    if devices is None:
-        return jax.make_mesh(shape, axes, axis_types=types)
-    return Mesh(np.asarray(devices, dtype=object).reshape(shape), axes,
-                axis_types=types)
-
-
-def device_mesh(device) -> Mesh:
-    """The one-device ``("data",)`` mesh a serving Engine runs under."""
-    return make_mesh((1,), ("data",), devices=[device])
+from repro.distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -48,12 +27,11 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return make_mesh(shape, axes)
 
 
-def make_smoke_mesh(n_model: int = 1, devices: Optional[Sequence] = None) -> Mesh:
-    """Small mesh over ``devices`` (default: every visible device):
-    (n/n_model, n_model) ``("data", "model")`` when ``n_model`` divides the
-    device count, else all of them on ``("data",)``."""
-    devices = list(jax.devices() if devices is None else devices)
-    n = len(devices)
+def make_smoke_mesh(n_model: int = 1) -> Mesh:
+    """Training mesh over every visible device: (n/n_model, n_model)
+    ``("data", "model")`` when ``n_model`` divides the device count, else
+    all of them on ``("data",)``."""
+    n = len(jax.devices())
     if n_model > 1 and n % n_model == 0:
-        return make_mesh((n // n_model, n_model), ("data", "model"), devices)
-    return make_mesh((n,), ("data",), devices)
+        return make_mesh((n // n_model, n_model), ("data", "model"))
+    return make_mesh((n,), ("data",))

@@ -63,9 +63,8 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import tensorizer as tz
+from repro.core.compile_cache import enable_compile_cache
 from repro.distributed import sharding as shd
-from repro.launch.cache import enable_compile_cache
-from repro.launch.mesh import make_smoke_mesh
 from repro.models import init_model
 from repro.serving.api import serve_api
 from repro.serving.engine import Engine, EngineConfig
@@ -214,7 +213,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "on this port instead of running the synthetic "
                          "traffic loop (0 = OS-assigned, -1 = off). Fronts "
                          "the single engine, or the Router with --hosts > 1")
-    ap.add_argument("--model-parallel", type=int, default=1)
     return ap
 
 
@@ -406,11 +404,8 @@ def main(argv=None) -> int:
         ap.error(WORKER_ON_TPU)
     enable_compile_cache()
     # an engine runs on one device (device 0); the Router puts host i on
-    # device i mod n. --model-parallel widens the mesh's model axis.
-    mesh = make_smoke_mesh(args.model_parallel,
-                           devices=jax.devices()[:args.model_parallel])
-
-    with shd.use_mesh(mesh):
+    # device i mod n
+    with shd.use_mesh(shd.device_mesh(jax.devices()[0])):
         params = init_model(cfg, jax.random.PRNGKey(0))
         if args.quantize == "serve":
             params = tz.quantize_params(params, predicate=_quant_predicate)
@@ -489,7 +484,7 @@ def main(argv=None) -> int:
                 target = Engine(cfg, params, ecfg,
                                 draft_params=draft_params)
                 front = "single engine"
-            srv = serve_api(target, port=args.api_port, mesh=mesh)
+            srv = serve_api(target, port=args.api_port)
             print(f"[serve] HTTP API on {srv.url} ({front}) — "
                   f"POST /v1/completions (SSE with \"stream\": true), "
                   f"/v1/embeddings, /v1/classify; GET /v1/stats /healthz",

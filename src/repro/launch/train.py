@@ -32,7 +32,7 @@ from repro.configs import get_config
 from repro.configs.base import ShapeCfg
 from repro.data import make_dataset
 from repro.distributed import sharding as shd
-from repro.launch.cache import enable_compile_cache
+from repro.core.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.models import init_model, steps as ST
 from repro.optim import adamw_init

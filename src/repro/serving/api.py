@@ -30,8 +30,7 @@ state, OPQ dispatch), so ONE driver thread owns the backend and runs the
 serve loop (step + harvest); HTTP handler threads (ThreadingHTTPServer)
 talk to it exclusively through a command queue and receive tokens through
 per-request stream queues. Each Engine enters its own device and mesh on
-whatever thread calls it, so the serve-loop thread needs no mesh;
-``mesh=`` additionally enters one around the loop.
+whatever thread calls it, so the serve-loop thread needs no mesh.
 
 Requests and responses carry token IDS, not text: tokenization is the
 client's business (the repo has no tokenizer dependency), which also keeps
@@ -40,7 +39,6 @@ the bit-identity story auditable end to end.
 
 from __future__ import annotations
 
-import contextlib
 import json
 import queue
 import threading
@@ -49,7 +47,6 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from repro.distributed import sharding as shd
 from repro.serving.engine import Engine
 from repro.serving.router import Router
 from repro.serving.sampling import SamplingParams
@@ -148,10 +145,9 @@ class _ServeLoop(threading.Thread):
     shutdown flushes ``("done", "shutdown")`` to every live stream so no
     SSE consumer is left hanging without a terminal frame."""
 
-    def __init__(self, backend: _Backend, mesh=None):
+    def __init__(self, backend: _Backend):
         super().__init__(daemon=True, name="serve-loop")
         self.backend = backend
-        self.mesh = mesh
         self.cmds: "queue.Queue" = queue.Queue()
         # not named _stop: threading.Thread defines an internal _stop()
         # method that join() calls, and shadowing it breaks teardown
@@ -216,31 +212,28 @@ class _ServeLoop(threading.Thread):
                 del self._streams[key]
 
     def run(self) -> None:
-        ctx = (shd.use_mesh(self.mesh) if self.mesh is not None
-               else contextlib.nullcontext())
-        with ctx:
-            while not self._halt.is_set():
+        while not self._halt.is_set():
+            try:
+                cmd = self.cmds.get(
+                    block=not self.backend.has_work(),
+                    timeout=_IDLE_WAIT_S)
+            except queue.Empty:
+                cmd = None
+            if self._halt.is_set():
+                break
+            if cmd is not None:
+                self._handle(cmd)
+                continue             # drain commands before stepping
+            if self.backend.has_work():
                 try:
-                    cmd = self.cmds.get(
-                        block=not self.backend.has_work(),
-                        timeout=_IDLE_WAIT_S)
-                except queue.Empty:
-                    cmd = None
-                if self._halt.is_set():
-                    break
-                if cmd is not None:
-                    self._handle(cmd)
-                    continue             # drain commands before stepping
-                if self.backend.has_work():
-                    try:
-                        self.backend.step()
-                    except Exception as exc:
-                        # a failed step poisons every live stream, not the
-                        # server: report and keep serving new requests
-                        for _, q, _, _ in self._streams.values():
-                            q.put(("error", f"{type(exc).__name__}: {exc}"))
-                        self._streams.clear()
-                    self._harvest()
+                    self.backend.step()
+                except Exception as exc:
+                    # a failed step poisons every live stream, not the
+                    # server: report and keep serving new requests
+                    for _, q, _, _ in self._streams.values():
+                        q.put(("error", f"{type(exc).__name__}: {exc}"))
+                    self._streams.clear()
+                self._harvest()
         # graceful shutdown: every stream still live gets a terminal frame
         # (an SSE consumer must never hang waiting on a dead server)
         for _, q, _, _ in self._streams.values():
@@ -428,13 +421,12 @@ class ApiServer:
             self.close()
 
 
-def serve_api(target, *, port: int = 0, host: str = "127.0.0.1",
-              mesh=None) -> ApiServer:
+def serve_api(target, *, port: int = 0,
+              host: str = "127.0.0.1") -> ApiServer:
     """Boot the HTTP serve API over an Engine or Router. Returns the
-    running :class:`ApiServer`. ``mesh`` (optional) is entered on the
-    serve-loop thread around the loop."""
+    running :class:`ApiServer`."""
     backend = _Backend(target)
-    loop = _ServeLoop(backend, mesh=mesh)
+    loop = _ServeLoop(backend)
     loop.start()
     httpd = ThreadingHTTPServer((host, port), _make_handler(loop))
     return ApiServer(loop, httpd)

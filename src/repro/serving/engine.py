@@ -9,20 +9,21 @@ retire, int8-KV aware). All device work is dispatched as OPQ instructions, so th
 paper's buffer-affinity scheduling and backup-task straggler mitigation apply
 to serving traffic, not just the Rodinia apps.
 
-Admission is *fused prefill-with-cache*: one bucketed forward per admission
-batch returns the first token AND the per-layer K/V in cache layout
-(models/serve.py ``prefill_with_cache``), which one batched donated scatter
-writes into all leased slot rows (serving/kv.py ``write_slots``). Seeding a
-prompt of length L therefore costs exactly one dispatched forward + one slot
-write per bucket — O(1) instructions instead of the old O(L) B=1 replay-decode
-chain — keeping admission on the matmul-bound side of the roofline (the GPTPU
-whole-kernel-offload argument applied to TTFT). Multi-bucket admission rounds
-dispatch their prefills concurrently and wait once, so buckets overlap on the
-OPQ lanes.
+Admission is *fused prefill-with-cache*: one bucketed forward per admitted
+request returns the first token AND the per-layer K/V in cache layout
+(models/serve.py ``prefill_with_cache``), which one donated scatter writes
+into the leased slot rows (serving/kv.py ``write_slots``). Seeding a prompt of
+length L therefore costs exactly one dispatched forward + one slot write —
+O(1) instructions instead of the old O(L) B=1 replay-decode chain — keeping
+admission on the matmul-bound side of the roofline (the GPTPU
+whole-kernel-offload argument applied to TTFT). Each prefill runs alone
+(width 1), so it is one program whatever arrives with it; an admission
+round dispatches all its prefills before it waits once.
 
 Decode semantics are *greedy and batch-invariant*: every slot computes exactly
 the math of a single-request decode at its own position (per-slot cache index,
-see models/attention.py), so staggered-arrival outputs are bit-identical to
+see models/attention.py) in the one fixed-width decode program, and every
+prefill runs alone, so concurrent or staggered arrivals are bit-identical to
 one-at-a-time sequential decoding — asserted in tests/test_serving.py, which
 also keeps a reference replay seeder proving fused admission is bit-identical
 to the replay era. MoE routing is per-request isolated: idle slots are masked
@@ -37,7 +38,7 @@ decode step — it never touches cache leaves. Backends: ``contiguous``
 per-slot block tables; ``lease`` returning False is admission backpressure
 when the pool runs dry), and ``recurrent`` (per-slot mamba/xlstm state rows —
 ssm and hybrid families serve through the same engine, admitted by a
-masked-scan prefill that is one dispatch per bucket like the dense path).
+masked-scan prefill that is one dispatch per request like the dense path).
 The paged store additionally runs block-native (``paged_native=True``): the
 decode step receives the pool + tables directly and attends in place — no
 gather-bridge view, peak decode working set = the pool — bit-identical to
@@ -70,7 +71,6 @@ from jax.sharding import Mesh
 from repro.configs.base import ArchConfig
 from repro.core.opq import OPQ, Buffer
 from repro.distributed import sharding as shd
-from repro.launch.mesh import device_mesh
 from repro.models import steps as ST
 from repro.serving.metrics import EngineMetrics, RequestMetrics, now
 from repro.serving.sampling import (
@@ -166,11 +166,11 @@ class EngineConfig:
         the recurrent prefill scan length.
     buckets
         Prefill pad lengths. Prompts are right-padded up to the smallest
-        bucket that holds them so same-bucket arrivals share one prefill
-        forward and the number of compiled prefill shapes is bounded by
-        ``len(buckets)``, not by traffic. ``None`` = powers of two from 16
-        capped at ``max_seq_len`` (scheduler.default_buckets). A bucket wider
-        than ``max_seq_len`` is rejected at construction.
+        bucket that holds them, and each prefills alone, so the number of
+        compiled prefill shapes is ``len(buckets)``, not set by traffic.
+        ``None`` = powers of two from 16 capped at ``max_seq_len``
+        (scheduler.default_buckets). A bucket wider than ``max_seq_len`` is
+        rejected at construction.
     eos_id
         Early-finish token id: a request retires when it emits this token
         (or at ``max_new_tokens``, whichever first). ``None`` = length-only.
@@ -280,7 +280,7 @@ def _jitted_steps(cfg: ArchConfig, kind: str, max_seq_len: int = 0,
     cache at that length); dense/moe callers pass 0 so engines with
     different seq budgets keep sharing one set of compiled executables.
     Dense-family prefill is the fused prefill-with-cache step: right-padded
-    bucket batch in, (first_tokens, per-layer K/V in cache layout) out —
+    bucket row(s) in, (first_tokens, per-layer K/V in cache layout) out —
     causal attention means pad tokens after a row's prompt never reach its
     logits or its K/V rows, so a small fixed bucket set is exact for any pad
     content. ``chunk`` additionally builds the chunked prefill step (same
@@ -393,7 +393,7 @@ class Engine:
         # one device per engine: its params, cache and every step it traces
         # or runs live there (the ambient one-device mesh, else device 0)
         if mesh is None:
-            mesh = shd.active_mesh() or device_mesh(jax.devices()[0])
+            mesh = shd.active_mesh() or shd.device_mesh(jax.devices()[0])
         if mesh.size != 1:
             raise ValueError(
                 f"an Engine runs on exactly one device, got a mesh of "
@@ -706,54 +706,47 @@ class Engine:
                     1, info["shared_blocks"], info["prefill_start"])
         return ok
 
-    def _prefix_group_key(self, slot: int, req: Request) -> int:
-        """Admission group key under the prefix cache: the slot's suffix
-        start CHUNK. A batched suffix prefill can only skip what every row
-        skips, so rows with different cached-prefix depths dispatch
-        separately — a cold arrival never forces a hot one to recompute its
-        cached prefix (scheduler.plan_admissions ``group_key``)."""
-        info = self.store.prefix_lease_info(slot)
-        return info["prefill_start"] // self.ecfg.block_size
+    def _prefix_start_chunk(self, slot: int) -> int:
+        """The first prompt chunk a prefix-cache slot's prefill computes:
+        its lease already holds every cached chunk before it."""
+        return (self.store.prefix_lease_info(slot)["prefill_start"]
+                // self.ecfg.block_size)
 
     def _admit(self) -> int:
-        """Fused admission: ONE dispatched prefill forward per bucket batch
-        (first token + cache payload out — per-layer K/V for dense families,
-        post-prompt state rows for recurrent ones) and ONE batched donated
-        scatter into the leased slot rows — zero B=1 replay decodes, seeding
-        cost O(1) instructions in prompt length. All buckets of the round are
-        dispatched before the first wait, so they overlap on the OPQ lanes.
-        Buckets wider than ``prefill_chunk`` dispatch the chunked prefill
-        step instead (long prompts — linear-in-S peak score memory, same
-        contract and bit-identical output). Returns the number of requests
-        admitted this round (step() uses 0 to detect a zero-progress
-        deferral with an idle engine)."""
+        """Fused admission: ONE dispatched prefill forward per admitted
+        request (first token + cache payload out — per-layer K/V for dense
+        families, post-prompt state rows for recurrent ones) and ONE donated
+        scatter into its leased slot rows — zero B=1 replay decodes, seeding
+        cost O(1) instructions in prompt length. Every prefill runs at width
+        1, so a request's prefill is the same program whatever arrives with
+        it: its first token and K/V cannot depend on its batchmates. (A
+        prefill batched at width N is another compiled program, and on a TPU
+        its rows differ from the width-1 ones — scripts/prefill_width.py.)
+        All prefills of the round are dispatched before the first wait, so
+        they queue back to back on the device. Buckets wider than
+        ``prefill_chunk`` dispatch the chunked prefill step instead (long
+        prompts — linear-in-S peak score memory, same contract and
+        bit-identical output); a prefix-cache hit dispatches the suffix
+        prefill from its first uncached chunk. Returns the number of
+        requests admitted this round (step() uses 0 to detect a
+        zero-progress deferral with an idle engine)."""
         pending = []
-        admitted = 0
-        group_key = self._prefix_group_key if self.ecfg.prefix_cache else None
-        for bucket, pairs in self.scheduler.plan_admissions(self._try_lease,
-                                                            group_key):
-            admitted += len(pairs)
-            toks = np.zeros((len(pairs), bucket), np.int32)
-            last = np.zeros((len(pairs),), np.int32)
-            presence = np.zeros((len(pairs), self.cfg.vocab_padded), bool)
-            for i, (slot, req) in enumerate(pairs):
-                toks[i, :len(req.prompt)] = req.prompt
-                last[i] = len(req.prompt) - 1
-                presence[i, req.prompt] = True
-                # the slot inherits the row's presence for decode steps
-                self._presence[slot, :] = presence[i]
-                req.metrics.admitted_s = now()
-            smp = stack_params([req.sampling for _, req in pairs], presence)
-            # prefix-cache hit groups resume the chunked scan mid-prompt:
-            # every row in the group shares this start chunk (the scheduler
-            # grouped by it), so no row recomputes a cached position and no
-            # row skips one it needs
-            start_chunk = (self._prefix_group_key(*pairs[0])
+        for bucket, slot, req in self.scheduler.plan_admissions(
+                self._try_lease):
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :len(req.prompt)] = req.prompt
+            last = np.asarray([len(req.prompt) - 1], np.int32)
+            presence = np.zeros((1, self.cfg.vocab_padded), bool)
+            presence[0, req.prompt] = True
+            # the slot inherits the row's presence for decode steps
+            self._presence[slot, :] = presence[0]
+            req.metrics.admitted_s = now()
+            smp = stack_params([req.sampling], presence)
+            start_chunk = (self._prefix_start_chunk(slot)
                            if self.ecfg.prefix_cache else 0)
             chunked = bucket in self._chunked_buckets
             if start_chunk > 0:
-                kv0 = self.store.gather_prefix_rows(
-                    [slot for slot, _ in pairs], bucket)
+                kv0 = self.store.gather_prefix_rows([slot], bucket)
                 fut = self._dispatch_async(
                     lambda p, t, li, k0, s, fn=self._prefill_suffix,
                     sc=start_chunk: fn(p, t, li, k0, sc, s),
@@ -774,14 +767,14 @@ class Engine:
                     self._params_buf, Buffer(toks, name=f"prefill{bucket}"),
                     Buffer(last), Buffer(smp, name="sampling"), flags=flag)
                 if self.ecfg.prefix_cache:
-                    # cold groups compute every block-size chunk — the unit
+                    # cold prefills compute every block-size chunk — the unit
                     # the prefix benchmark counts dispatched prefill work in
                     self.metrics.prefill_chunks += (
                         bucket // self.ecfg.block_size)
             # speculative: the draft cache must hold the prompt through the
-            # DRAFT's own weights, so every admission group also dispatches a
-            # draft prefill (always the full prompt — the draft store has no
-            # prefix cache, and a suffix-group target skip never applies to it)
+            # DRAFT's own weights, so every admission also dispatches a draft
+            # prefill (always the full prompt — the draft store has no prefix
+            # cache, and a suffix skip of the target never applies to it)
             dfut = None
             if self.draft_store is not None:
                 dfut = self._dispatch_async(
@@ -789,39 +782,35 @@ class Engine:
                     self._draft_params_buf,
                     Buffer(toks, name=f"draft-prefill{bucket}"),
                     Buffer(last), flags=f"draft_prefill/{bucket}")
-            pending.append((pairs, last, fut, dfut))
-        for pairs, last, fut, dfut in pending:
+            pending.append((slot, req, fut, dfut))
+        for slot, req, fut, dfut in pending:
             t0 = now()
             first, kv, lp = fut.result()
-            first = np.asarray(first)
+            tok = int(np.asarray(first)[0])
             self.metrics.prefill_wait_s += now() - t0
             self.metrics.prefill_batches += 1
-            self.metrics.prefill_tokens += int(last.sum()) + len(pairs)
+            self.metrics.prefill_tokens += len(req.prompt)
             t0 = now()
-            self._seed_admitted(pairs, kv)
+            self._seed_admitted(slot, req, kv)
             if dfut is not None:
                 # draft first token discarded — the TARGET's prefill token is
                 # the emitted one; the draft only needed its cache seeded
                 _, dkv = dfut.result()
-                self.draft_store.write_slots(
-                    [slot for slot, _ in pairs], dkv,
-                    [len(req.prompt) for _, req in pairs])
+                self.draft_store.write_slots([slot], dkv, [len(req.prompt)])
             self.metrics.seed_write_s += now() - t0
-            for i, (slot, req) in enumerate(pairs):
-                req.state = RequestState.RUNNING
-                tok = int(first[i])
-                req.tokens.append(tok)
-                req.token_ts.append(now())
-                self._record_logprob(req, lp, i)
-                self._presence[slot, tok] = True
-                if req.sampling is not None and not req.sampling.greedy:
-                    self.metrics.sampled_tokens += 1
-                req.metrics.first_token_s = now()
-                req.metrics.n_generated = 1
-                self.metrics.observe_tokens(1)
-                if self._finished(req):       # done at the prefill token:
-                    self._retire(slot)        # reset scrubs the seeded row
-        return admitted
+            req.state = RequestState.RUNNING
+            req.tokens.append(tok)
+            req.token_ts.append(now())
+            self._record_logprob(req, lp, 0)
+            self._presence[slot, tok] = True
+            if req.sampling is not None and not req.sampling.greedy:
+                self.metrics.sampled_tokens += 1
+            req.metrics.first_token_s = now()
+            req.metrics.n_generated = 1
+            self.metrics.observe_tokens(1)
+            if self._finished(req):           # done at the prefill token:
+                self._retire(slot)            # reset scrubs the seeded row
+        return len(pending)
 
     def _record_logprob(self, req: Request, lp, idx) -> None:
         """Append one emitted token's logprob record from a step's
@@ -846,13 +835,12 @@ class Engine:
         return stack_params(self.scheduler.sampling_by_slot(GREEDY),
                             self._presence.copy())
 
-    def _seed_admitted(self, pairs, kv) -> None:
-        """Seed every leased row of one admission bucket from the fused
-        prefill's payload — one batched donated scatter through the store.
-        Overridable seam: tests substitute the PR-1 B=1 replay seeder here to
-        prove fused admission is bit-identical to prompt replay."""
-        self.store.write_slots([slot for slot, _ in pairs], kv,
-                               [len(req.prompt) for _, req in pairs])
+    def _seed_admitted(self, slot: int, req: Request, kv) -> None:
+        """Seed one admitted slot's leased rows from the fused prefill's
+        payload — one donated scatter through the store. Overridable seam:
+        tests substitute the PR-1 B=1 replay seeder here to prove fused
+        admission is bit-identical to prompt replay."""
+        self.store.write_slots([slot], kv, [len(req.prompt)])
 
     def _decode_once(self) -> None:
         toks, active = self.scheduler.decode_batch()
@@ -1304,7 +1292,7 @@ class Engine:
             out["opq"] = dict(self.opq.stats)
             # per-flag instruction counts: the dispatch-shape audit trail
             # (tests assert admission issues one prefill/<bucket> instruction
-            # per bucket batch and zero replay decodes)
+            # per admitted request and zero replay decodes)
             out["opq"]["flags"] = dict(self.opq.flag_counts)
         return out
 

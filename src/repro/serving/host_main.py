@@ -48,15 +48,14 @@ def serve(sock_path: str) -> int:
     # heavy imports AFTER the socket handshake so a connect failure is fast
     import jax
     from repro.distributed import sharding as shd
-    from repro.launch.cache import enable_compile_cache
-    from repro.launch.mesh import device_mesh
+    from repro.core.compile_cache import enable_compile_cache
     from repro.serving.engine import Engine
     from repro.serving.transport import (
         EngineHost, engine_cfg_from_wire, realize_model_spec,
     )
 
     enable_compile_cache()
-    mesh = device_mesh(jax.devices()[0])
+    mesh = shd.device_mesh(jax.devices()[0])
     with shd.use_mesh(mesh):
         cfg, params, draft_cfg, draft_params = realize_model_spec(spec)
         ecfg = engine_cfg_from_wire(init["args"]["engine_cfg"],

@@ -110,7 +110,7 @@ class EngineMetrics:
     embed_requests: int = 0                    # non-generative forwards
                                                # (serve API embeddings/
                                                # classification)
-    prefill_batches: int = 0
+    prefill_batches: int = 0                   # prefill dispatches, one per admission
     prefill_tokens: int = 0                    # unpadded prompt tokens prefilled
     prefill_chunks: int = 0                    # block-size prefill chunks
                                                # actually computed (prefix-

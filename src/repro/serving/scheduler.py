@@ -5,10 +5,10 @@ requests as there are free slots into the in-flight decode batch and retires
 finished ones — there is no full-batch barrier, a long request never blocks
 short ones from entering and leaving around it.
 
-Admissions are grouped by *prefill bucket* (prompt padded up to a small fixed
-set of lengths) so same-bucket arrivals share one prefill forward and the
-number of distinct compiled prefill shapes is bounded by ``len(buckets)``
-instead of the number of distinct prompt lengths seen in traffic.
+Each admission is prefilled alone, its prompt padded up to a small fixed set
+of *prefill buckets*: the number of compiled prefill shapes is
+``len(buckets)``, independent of both the prompt lengths and the arrival
+pattern seen in traffic.
 """
 
 from __future__ import annotations
@@ -66,27 +66,18 @@ class Scheduler:
 
     # ------------------------------------------------------------ join/retire
 
-    def plan_admissions(self, try_lease=None, group_key=None
-                        ) -> List[Tuple[int, List[Tuple[int, object]]]]:
-        """Lease free slots to waiting requests (FIFO), grouped by prefill
-        bucket: [(bucket_len, [(slot, request), ...]), ...]. Mutates the free
+    def plan_admissions(self, try_lease=None
+                        ) -> List[Tuple[int, int, object]]:
+        """Lease free slots to waiting requests (FIFO):
+        [(bucket_len, slot, request), ...] in arrival order. Mutates the free
         list and active map — the engine must prefill every planned request.
 
         ``try_lease(slot, request) -> bool`` lets the cache backend reserve
         capacity before the slot is committed (serving/store.py). A False
         return stops planning with the request still at the queue head —
         FIFO-order admission backpressure (e.g. paged block-pool exhaustion),
-        resolved when a retire frees capacity.
-
-        ``group_key(slot, request)`` further partitions a bucket's admissions
-        (evaluated AFTER the lease, so the key can read what the lease
-        reserved). The prefix-cache engine keys by suffix start chunk: a
-        batched prefill can only skip chunks every row in it skips, so mixing
-        a hot-prefix row with a cold one would silently recompute the hot
-        row's cached prefix — separate groups keep each dispatch's skip at
-        its own rows' minimum. A bucket may therefore appear more than once
-        in the result, once per distinct key."""
-        groups: Dict[Tuple[int, int], List[Tuple[int, object]]] = {}
+        resolved when a retire frees capacity."""
+        planned: List[Tuple[int, int, object]] = []
         while self.waiting and self.free:
             req = self.waiting[0]
             slot = self.free[-1]
@@ -95,10 +86,9 @@ class Scheduler:
             self.waiting.popleft()
             self.free.pop()
             self.active[slot] = req
-            b = bucket_for(len(req.prompt), self.buckets)
-            key = (b, group_key(slot, req) if group_key is not None else 0)
-            groups.setdefault(key, []).append((slot, req))
-        return [(b, pairs) for (b, _), pairs in sorted(groups.items())]
+            planned.append((bucket_for(len(req.prompt), self.buckets), slot,
+                            req))
+        return planned
 
     def admit_seeded(self, request) -> "int | None":
         """Place an externally-seeded request straight into the in-flight

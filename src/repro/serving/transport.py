@@ -56,8 +56,7 @@ from typing import Dict, List, Optional, Protocol, Sequence
 import jax
 import numpy as np
 
-from repro.launch.cache import ENV as CACHE_ENV, compile_cache_dir
-from repro.launch.mesh import device_mesh
+from repro.distributed.sharding import device_mesh
 from repro.serving.engine import (
     Engine, EngineConfig, QueueFull, Request, RequestState,
 )
@@ -674,10 +673,10 @@ class SubprocessTransport:
         env = dict(os.environ)
         src_dir = str(Path(repro.__file__).resolve().parents[1])
         existing = env.get("PYTHONPATH")
+        # the worker imports this same checkout, so its compile cache
+        # (core/compile_cache.py) resolves to the parent's directory
         env["PYTHONPATH"] = (src_dir + os.pathsep + existing
                              if existing else src_dir)
-        # sibling workers share one persistent compilation cache
-        env[CACHE_ENV] = compile_cache_dir()
         return env
 
     # --------------------------------------------------------------- rpc

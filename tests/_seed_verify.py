@@ -151,7 +151,7 @@ def main(argv=None) -> int:
 
     from repro.configs import get_config
     from repro.distributed import sharding as shd
-    from repro.launch.mesh import make_mesh
+    from repro.distributed.sharding import make_mesh
     from repro.models import init_model
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])

@@ -1,8 +1,8 @@
+import jax
 import pytest
 
+from repro.core.compile_cache import enable_compile_cache
 from repro.distributed import sharding as shd
-from repro.launch.cache import enable_compile_cache
-from repro.launch.mesh import make_mesh
 
 # NOTE: no XLA_FLAGS here on purpose — tests run on the real single CPU
 # device; only launch/dryrun.py forces 512 host devices (assignment step 0).
@@ -14,7 +14,7 @@ enable_compile_cache()
 
 @pytest.fixture(scope="session")
 def mesh():
-    m = make_mesh((1,), ("data",))
+    m = shd.device_mesh(jax.devices()[0])
     shd.set_mesh(m)
     with m:
         yield m

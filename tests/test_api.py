@@ -13,9 +13,9 @@
                    are 4xx JSON errors, never hung sockets; /healthz and
                    /v1/stats serve while traffic decodes
 
-The server is booted in-process on port 0 (OS-assigned) with the session
-mesh passed through — the serve loop thread must enter the mesh itself
-because jax's active-mesh state is thread-local.
+The server is booted in-process on port 0 (OS-assigned). The serve loop
+thread takes no mesh: the Engine enters its own device and mesh on every
+call, whichever thread makes it.
 """
 
 import http.client
@@ -42,7 +42,7 @@ def params():
 def api(params, mesh):
     """A fresh engine behind a port-0 API server, torn down per test."""
     eng = Engine(CFG, params, EngineConfig(max_slots=2, max_seq_len=32))
-    srv = serve_api(eng, port=0, mesh=mesh)
+    srv = serve_api(eng, port=0)
     yield srv, eng
     srv.close()
     eng.close()
@@ -107,7 +107,7 @@ def test_completion_matches_direct_engine(params, mesh):
     ref_eng.close()
 
     eng = Engine(CFG, params, EngineConfig(max_slots=2, max_seq_len=32))
-    srv = serve_api(eng, port=0, mesh=mesh)
+    srv = serve_api(eng, port=0)
     try:
         status, body = _request(srv, "POST", "/v1/completions",
                                 {"prompt": prompt, "max_new_tokens": 8})
@@ -209,7 +209,7 @@ def test_embeddings_match_direct_embed(params, mesh):
     ref_eng.close()
 
     eng = Engine(CFG, params, EngineConfig(max_slots=2, max_seq_len=32))
-    srv = serve_api(eng, port=0, mesh=mesh)
+    srv = serve_api(eng, port=0)
     try:
         status, body = _request(srv, "POST", "/v1/embeddings",
                                 {"prompt": prompt})

@@ -21,7 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import load_checkpoint, save_checkpoint, latest_step
 from repro.configs import get_config
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_mesh
+from repro.distributed.sharding import make_mesh
 from repro.ft import plan_elastic_mesh
 from repro.models import init_model, steps, param_specs
 from repro.optim import adamw_init

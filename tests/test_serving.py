@@ -105,14 +105,13 @@ class _ReplaySeededEngine(Engine):
         self._replay = jax.jit(ST.make_decode_step(cfg))
         self._replay_template = SV.init_cache(cfg, 1, self.ecfg.max_seq_len)
 
-    def _seed_admitted(self, pairs, kv):
+    def _seed_admitted(self, slot, req, kv):
         del kv                               # fused prefill K/V ignored
-        for slot, req in pairs:
-            rc = self._replay_template
-            for t in req.prompt:
-                _, rc = self._replay(
-                    self.params, rc, {"tokens": jnp.asarray([[int(t)]], jnp.int32)})
-            self.kv.write_slot(slot, rc, n_valid=len(req.prompt))
+        rc = self._replay_template
+        for t in req.prompt:
+            _, rc = self._replay(
+                self.params, rc, {"tokens": jnp.asarray([[int(t)]], jnp.int32)})
+        self.kv.write_slot(slot, rc, n_valid=len(req.prompt))
 
 
 def _pure_sequential_decode(cfg, params, prompt, gen, max_seq):
@@ -336,24 +335,24 @@ def test_fused_seeding_bit_identical_to_replay(params, moe_params, family, kv_dt
 
 def test_admission_is_one_forward_per_bucket_no_replay(params):
     """Dispatch-shape audit via OPQ instruction flags: an admission round
-    issues exactly ONE prefill instruction per bucket batch (same-bucket
-    arrivals share it) and ZERO replay decodes — seeding is O(1) dispatches
-    in prompt length."""
+    issues exactly ONE prefill instruction per admitted request (each at
+    width 1, same-bucket arrivals included) and ZERO replay decodes —
+    seeding is O(1) dispatches in prompt length."""
     eng = Engine(CFG, params, EngineConfig(max_slots=4, max_seq_len=32))
     for l, g in ((3, 4), (9, 3), (20, 5)):       # buckets: 16, 16, 32
         eng.submit(_prompts([l])[0], g)
     eng.step()
     flags = eng.stats()["opq"]["flags"]
-    assert flags["prefill/16"] == 1              # two prompts, one forward
+    assert flags["prefill/16"] == 2              # two prompts, one forward each
     assert flags["prefill/32"] == 1
     eng.run_until_complete()
     s = eng.stats()
     flags = s["opq"]["flags"]
-    # the complete run's instruction ledger: per-bucket prefills and batched
+    # the complete run's instruction ledger: per-request prefills and batched
     # decode steps, nothing else — the replay instruction class is extinct
     assert set(flags) == {"prefill/16", "prefill/32", "decode"}
     assert sum(c for f, c in flags.items()
-               if f.startswith("prefill/")) == s["prefill_batches"] == 2
+               if f.startswith("prefill/")) == s["prefill_batches"] == 3
     assert flags["decode"] == s["decode_steps"]
     eng.close()
 
@@ -406,8 +405,9 @@ def test_moe_idle_mask_restores_isolation(moe_params):
 
 
 def test_bucketing_bounds_prefill_shapes(params):
-    """Prompts of many lengths compile at most len(buckets) prefill shapes,
-    and same-step same-bucket arrivals share one prefill batch."""
+    """Prompts of many lengths compile at most len(buckets) prefill shapes:
+    each admission prefills alone at width 1, so same-step same-bucket
+    arrivals run the one (1, bucket) program twice."""
     assert default_buckets(48) == (16, 32, 48)
     assert default_buckets(32) == (16, 32)
     assert bucket_for(5, (16, 32)) == 16 and bucket_for(17, (16, 32)) == 32
@@ -417,7 +417,7 @@ def test_bucketing_bounds_prefill_shapes(params):
     for l in (3, 9):                              # both land in the 16-bucket
         eng.submit(_prompts([l])[0], 2)
     eng.step()
-    assert eng.stats()["prefill_batches"] == 1    # one shared prefill forward
+    assert eng.stats()["prefill_batches"] == 2    # one width-1 forward each
     eng.run_until_complete()
     eng.close()
 
@@ -755,7 +755,7 @@ def test_engine_runs_on_one_device(params, mesh):
 
 
 def test_compile_cache_dir_follows_the_environment(monkeypatch):
-    from repro.launch.cache import CHECKOUT_CACHE, ENV, compile_cache_dir
+    from repro.core.compile_cache import CHECKOUT_CACHE, ENV, compile_cache_dir
     monkeypatch.setenv(ENV, "/elsewhere/cache")
     assert compile_cache_dir() == "/elsewhere/cache"
     monkeypatch.delenv(ENV)

@@ -543,7 +543,7 @@ def test_spec_opq_flags_and_metrics_reconcile(params):
 
 def test_plain_engine_flag_set_unchanged(params):
     """Guard: a NON-speculative engine's dispatch shapes are untouched by
-    the spec machinery — exactly one prefill flag per bucket plus plain
+    the spec machinery — prefill flags of the one bucket plus plain
     decode, nothing draft- or verify-shaped."""
     eng = Engine(CFG, params, EngineConfig(max_slots=2, max_seq_len=32))
     prompts, gens = _traffic(CFG.vocab, lens=(6, 12), gens=(4, 4))

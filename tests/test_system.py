@@ -52,7 +52,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_mesh
+from repro.distributed.sharding import make_mesh
 from repro.models import init_model, steps
 from repro.optim import adamw_init
 

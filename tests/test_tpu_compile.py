@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.distributed import sharding as shd
-from repro.launch.mesh import device_mesh
+from repro.distributed.sharding import device_mesh
 from repro.models import init_model, steps as ST
 from repro.serving.store import make_store
 
@@ -110,9 +110,10 @@ def test_paged_native_decode_step_compiles(one_chip, params, use_kernel):
 
 
 def test_fused_prefill_step_compiles(one_chip, params):
+    # width 1: the Engine prefills every admitted request alone
     tokens, last = _on(one_chip, (
-        jax.ShapeDtypeStruct((SLOTS, BUCKET), jnp.int32),
-        jax.ShapeDtypeStruct((SLOTS,), jnp.int32)))
+        jax.ShapeDtypeStruct((1, BUCKET), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32)))
     step = jax.jit(ST.make_prefill_with_cache_step(CFG))
     compiled = step.lower(params, tokens, last).compile()
     _fits(compiled)
