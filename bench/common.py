@@ -3,8 +3,10 @@
 Everything that belongs to one configuration, one traffic mix, one cell or
 one metric is a file of its own under ``bench/``:
 
-  configs/<config>.json     sizes as run, source, cuts, deployment, limit
-  references/<family>.py    the plain float32 reference of that family
+  configs/<config>.json     sizes as run, source, cuts, departures from the
+                            source, deployment, limits
+  references/<family>.py    the plain float32 reference of that family,
+                            ``score(c, seed, seqs, n_prompt)``
   mixes/<traffic>.json      traffic parameters for the general generator
   cells/<workload>.json     the cell's offered rate
   metrics/<metric>.py       one reader per metric, ``read(run) -> float|None``

@@ -4,12 +4,16 @@
         [--control-seeds 4,5,6] [--seconds 10]
 
 For each seed it runs the cell as ``bench/run.py`` does (the cell's mix at
-its rate, a short window) and prints the compared numbers: with the program
-as the configuration states it (the lower reading is the largest
-``max_logit_gap`` over these seeds), then with the control, the program's
-own lower-precision path switched on (Tensorizer W8A8 weights and
-activations; the upper reading is the smallest over its seeds). One line of
-JSON per run, prefixed ``[control]``, with every scored token's gap. Needs the chip the cell asks for.
+its rate) and prints every number the check reads: with the program as the
+configuration states it (a number's lower reading is its largest over
+these seeds), then with the control, the program's own lower-precision
+path switched on (Tensorizer W8A8 weights and activations; the upper
+reading is the smallest over its seeds). One line of JSON per run,
+prefixed ``[control]``, with the cell's end-to-end metrics (``setup_s``
+counts from the process start, so only the first run's is a set-up) and
+every scored token's gap and router margin, request by request (a
+request's first row is its prefill's token). Needs the chip the cell asks
+for.
 """
 
 import time
@@ -38,18 +42,21 @@ def main() -> int:
     run.chips_or_exit(w["chips"])
     from bench import harness
     mix, cell = common.mix(w["traffic"]), common.cell(w["name"])
+    metrics = [m for m in common.benchmark()["end_to_end"]
+               if w["name"] in m.get("workloads", [w["name"]])]
     plan = [("program", "off", s) for s in args.seeds.split(",") if s] + \
         [("control", "serve", s) for s in args.control_seeds.split(",") if s]
     for kind, quantize, seed in plan:
         t0 = time.monotonic()
         res = harness.run_cell(c, mix, cell, int(seed), args.seconds, False,
-                               [], t_process=T_PROCESS, quantize=quantize,
-                               keep_gaps=True)
+                               metrics, t_process=T_PROCESS,
+                               quantize=quantize, keep_scored=True)
         print("[control] " + json.dumps(run.finite({
             "workload": w["name"], "kind": kind, "seed": int(seed),
             "correct": res["correct"], "failed": res["failed"],
             "attempted": res["attempted"], "readings": res["readings"],
-            "checks": res["checks"], "gaps": res["gaps"],
+            "checks": res["checks"], "metrics": res["metrics"],
+            "scored": res["scored"],
             "wall_s": time.monotonic() - t0})), flush=True)
     return 0
 

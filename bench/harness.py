@@ -39,9 +39,9 @@ from bench import program
 from bench import trace as tracing
 
 TRACE_AFTER_S = 2.0
-SAMPLE_TOKENS = 512        # served tokens the check scores, at least
-SAMPLE_MAX = 16            # requests the check scores, at most
-TRIM = 0.02                # share of largest gaps trimmed_mean_gap leaves out
+SAMPLE_TOKENS = 512        # served tokens the check scores, at least,
+                           # unless the configuration sets check_tokens
+SAMPLE_MAX = 32            # requests the check scores, at most
 
 
 @dataclasses.dataclass
@@ -190,10 +190,10 @@ def collect(r, recs: List[Record]) -> None:
             rec.admitted_s = q.metrics.admitted_s
 
 
-def sample(recs: List[Record], seed: int) -> List[Record]:
+def sample(recs: List[Record], seed: int,
+           tokens: int = SAMPLE_TOKENS) -> List[Record]:
     """The finished requests the check scores, of any phase: the longest,
-    then others drawn from the seed until ``SAMPLE_TOKENS`` served
-    tokens."""
+    then others drawn from the seed until ``tokens`` served tokens."""
     done = [x for x in recs if x.done]
     if not done:
         return []
@@ -202,7 +202,7 @@ def sample(recs: List[Record], seed: int) -> List[Record]:
     rng = np.random.default_rng([seed, 2])
     order = rng.permutation(len(rest))
     for j in order:
-        if sum(len(x.tokens) for x in out) >= SAMPLE_TOKENS or \
+        if sum(len(x.tokens) for x in out) >= tokens or \
                 len(out) >= SAMPLE_MAX:
             break
         out.append(rest[j])
@@ -212,8 +212,9 @@ def sample(recs: List[Record], seed: int) -> List[Record]:
 def check(c: Dict, seed: int, recs: List[Record]):
     """The numbers ``correct`` compares, each with its limit.
 
-    lost: window requests accepted that never sent a token (limit 0); one
-    still streaming at the tail's end is late, counted in ``failed``.
+    lost: window requests that never sent a token, refused ones included
+    (limit 0); one still streaming at the tail's end is late, counted in
+    ``failed``.
     short: finished requests with fewer tokens than asked (limit 0).
     Over a sample of finished requests (:func:`sample`), the plain float32
     reference is run over each prompt and its served tokens, and each
@@ -221,40 +222,57 @@ def check(c: Dict, seed: int, recs: List[Record]):
     read (0 where it is the reference's argmax):
     max_logit_gap: the widest gap.
     mean_logit_gap: the mean gap over every scored token.
-    trimmed_mean_gap: the mean gap with the largest ``TRIM`` of the scored
-    tokens' gaps left out. In MoE a near-tie among router logits that bf16
-    and float32 break apart moves a token's whole expert set, and such a
-    token's gap swings the widest and the mean gap from seed to seed; the
-    trimmed mean is steady and reads the precision of the rest.
+    first_token_max_gap: the widest gap among each request's first token,
+    the one its prefill produces.
+    untied_mean_gap (configurations with a ``router_tie_margin``): the
+    mean gap over each request's first token and every later token whose
+    position the reference routes with a margin of at least
+    ``router_tie_margin`` in every layer (the k-th router logit above the
+    (k+1)-th). Where the margin is smaller, bf16 and float32 may pick
+    different experts, and the gap there measures the tie, not the program;
+    the first tokens, the prefill's only output, always count, so a fault
+    of the prefill cannot hide behind the rule.
     Each is compared where the configuration file gives it a limit
     (``correct``); one without a limit is reported under ``readings`` only.
-    Returns the checks and every scored token's gap."""
+    Returns the checks and every scored request's per-token rows."""
     win = [x for x in recs if x.phase == "window"]
-    lost = sum(1 for x in win if not x.rejected and not x.tokens)
+    lost = sum(1 for x in win if not x.tokens)
     short = sum(1 for x in recs if x.done
                 and len(x.tokens) != x.max_new_tokens)
-    picked = sample(recs, seed)
-    gaps = common.reference(c["family"]).logit_gaps(
+    picked = sample(recs, seed, c.get("check_tokens", SAMPLE_TOKENS))
+    scored = common.reference(c["family"]).score(
         c, seed, [np.concatenate([x.prompt, np.asarray(x.tokens, np.int32)])
                   for x in picked],
         [len(x.prompt) for x in picked]) if picked else []
-    allg = np.concatenate(gaps) if gaps else np.full(1, np.inf)
     out = {"lost": {"value": lost, "limit": 0},
            "short": {"value": short, "limit": 0}}
-    k = int(np.ceil(TRIM * allg.size))
-    trimmed = np.sort(allg)[:allg.size - k] if allg.size > k else allg
-    for name, value in (("max_logit_gap", float(np.max(allg))),
-                        ("mean_logit_gap", float(np.mean(allg))),
-                        ("trimmed_mean_gap", float(np.mean(trimmed)))):
+    allg = np.concatenate([s["gap"] for s in scored]) if scored \
+        else np.full(1, np.inf)
+    first = np.asarray([s["gap"][0] for s in scored]) if scored else allg
+    values = [("max_logit_gap", float(np.max(allg))),
+              ("mean_logit_gap", float(np.mean(allg))),
+              ("first_token_max_gap", float(np.max(first)))]
+    tie = c.get("router_tie_margin")
+    if tie is not None:
+        margin = np.concatenate([s["router_margin"] for s in scored]) \
+            if scored else np.zeros(1)
+        is_first = np.concatenate([np.arange(s["gap"].size) == 0
+                                   for s in scored]) if scored \
+            else np.ones(1, bool)
+        kept = allg[(margin >= tie) | is_first]
+        values.append(("untied_mean_gap", float(np.mean(kept))))
+        print(f"[bench] untied or first tokens (router margin >= {tie} in "
+              f"every layer): {kept.size} of {allg.size}", file=sys.stderr,
+              flush=True)
+    for name, value in values:
         out[name] = {"value": value, "limit": c["correct"].get(name)}
-    if gaps:
+    if scored:
         print(f"[bench] logit gaps over {allg.size} served tokens of "
-              f"{len(gaps)} requests: {int(np.sum(allg > 0))} nonzero, sum "
-              f"{float(np.sum(allg))}, first tokens max "
-              f"{max(float(g[0]) for g in gaps):.4g}, largest "
+              f"{len(scored)} requests: {int(np.sum(allg > 0))} nonzero, sum "
+              f"{float(np.sum(allg))}, largest "
               f"{[round(float(x), 4) for x in np.sort(allg)[::-1][:16]]}",
               file=sys.stderr, flush=True)
-    return out, allg
+    return out, scored
 
 
 def passed(checks: Dict[str, Dict]) -> bool:
@@ -272,7 +290,7 @@ def peak_row(kind: str) -> Dict:
 def run_cell(c: Dict, mix: Dict, cell: Dict, seed: int, seconds: float,
              trace: bool, metrics: List[Dict], *, t_process: float,
              quantize: str = "off", trace_dir: Optional[str] = None,
-             keep_gaps: bool = False,
+             keep_scored: bool = False,
              log=lambda s: print(s, file=sys.stderr, flush=True)) -> Dict:
     """One run. ``cell`` is the cell's file (``rate_per_s``, ``lead_s``,
     ``tail_s``); ``metrics`` are the BENCHMARK.json entries to report;
@@ -307,10 +325,9 @@ def run_cell(c: Dict, mix: Dict, cell: Dict, seed: int, seconds: float,
     del r
     gc.collect()
     t_ref = time.monotonic()
-    checks, gaps = check(c, seed, recs)
+    checks, scored = check(c, seed, recs)
     log(f"[bench] reference check took {time.monotonic() - t_ref:.1f} s "
-        f"over {sum(len(x.tokens) for x in sample(recs, seed))} served "
-        f"tokens")
+        f"over {sum(row['gap'].size for row in scored)} served tokens")
     tr = None
     if span is not None:
         path = tracing.find_xplane(trace_dir)
@@ -347,8 +364,9 @@ def run_cell(c: Dict, mix: Dict, cell: Dict, seed: int, seconds: float,
         f"{len(win)}, rejected {sum(1 for x in win if x.rejected)}")
     # every reading, compared or not, then (last) the compared ones
     result["readings"] = {k: v["value"] for k, v in checks.items()}
-    if keep_gaps:
-        result["gaps"] = [round(float(x), 6) for x in gaps]
+    if keep_scored:
+        result["scored"] = [{k: [round(float(x), 6) for x in v]
+                             for k, v in row.items()} for row in scored]
     result["checks"] = {k: v for k, v in checks.items()
                         if v["limit"] is not None}
     return result

@@ -73,25 +73,29 @@ def mlp(p: Dict, h, c: Dict):
 
 
 def dense_ffn(lp: Dict, h, c: Dict):
-    return mlp(lp["mlp"], h, c)
+    """The MLP's output, and no router margin (the dense family routes
+    nothing)."""
+    return mlp(lp["mlp"], h, c), None
 
 
 def make_layer(ffn: Callable, c: Dict):
     @jax.jit
     def layer(lp, x):
         x = x + attention(lp["attn"], norm(lp["ln1"], x, c), c)
-        return x + ffn(lp, norm(lp["ln2"], x, c), c)
+        y, margin = ffn(lp, norm(lp["ln2"], x, c), c)
+        return x + y, margin
     return layer
 
 
 def hidden(c: Dict, seed: int, seqs: Sequence[np.ndarray],
            ffn: Callable = dense_ffn):
-    """The unstacked weights and each sequence's last hidden states (padded
-    at the end, which causal attention never reads back), one layer at a
-    time."""
+    """The unstacked weights, each sequence's last hidden states (padded at
+    the end, which causal attention never reads back), one layer at a time,
+    and each position's smallest router margin over the layers (None where
+    ``ffn`` routes nothing)."""
     top = layer_params(c, seed, None)
     layer = make_layer(ffn, c)
-    xs = []
+    xs, margins = [], [None] * len(seqs)
     for s in seqs:
         # powers of two from Q_BLOCK up: few shapes, each compiled once
         ids = np.zeros(max(Q_BLOCK, 1 << (len(s) - 1).bit_length()),
@@ -100,35 +104,47 @@ def hidden(c: Dict, seed: int, seqs: Sequence[np.ndarray],
         xs.append(top["embed"][jnp.asarray(ids)])
     for li in range(c["num_hidden_layers"]):
         lp = layer_params(c, seed, li)
-        xs = [layer(lp, x) for x in xs]
+        outs = [layer(lp, x) for x in xs]
+        xs = [x for x, _ in outs]
+        margins = [a if m is None else jnp.minimum(m, a)
+                   for m, (_, a) in zip(margins, outs)] \
+            if outs and outs[0][1] is not None else margins
         del lp
-    return top, xs
+    return top, xs, margins
 
 
 def logits(c: Dict, seed: int, seq: np.ndarray, ffn: Callable = dense_ffn):
     """Every position's logits of one sequence (for small tests)."""
     with jax.default_matmul_precision("highest"):
-        top, (x,) = hidden(c, seed, [seq], ffn)
+        top, (x,), _ = hidden(c, seed, [seq], ffn)
         h = norm(top["final_ln"], x[:len(seq)], c)
         return np.asarray((h @ top["lm_head"])[:, :c["vocab_size"]])
 
 
-def logit_gaps(c: Dict, seed: int, seqs: Sequence[np.ndarray],
-               n_prompt: Sequence[int], ffn: Callable = dense_ffn
-               ) -> List[np.ndarray]:
-    """For each sequence (prompt + served tokens), the gap by which each
-    served token's logit lies below the reference's best at its position:
-    ``max(logits[p]) - logits[p][seq[p + 1]]`` for p from the last prompt
-    position to the second-to-last token. 0 where the served token is the
-    reference's argmax."""
+def score(c: Dict, seed: int, seqs: Sequence[np.ndarray],
+          n_prompt: Sequence[int], ffn: Callable = dense_ffn
+          ) -> List[Dict[str, np.ndarray]]:
+    """For each sequence (prompt + served tokens), one entry per served
+    token, at the position p that predicts it (from the last prompt
+    position to the second-to-last token):
+
+    ``gap``: by how much the served token's logit lies below the
+    reference's best, ``max(logits[p]) - logits[p][seq[p + 1]]``; 0 where
+    it is the reference's argmax.
+    ``router_margin`` (routed families only): the smallest, over the
+    layers, of the reference's margin between the last router logit that
+    picks an expert at p and the first that does not."""
     with jax.default_matmul_precision("highest"):
-        top, xs = hidden(c, seed, seqs, ffn)
+        top, xs, margins = hidden(c, seed, seqs, ffn)
         out = []
-        for s, n, x in zip(seqs, n_prompt, xs):
+        for s, n, x, m in zip(seqs, n_prompt, xs, margins):
             pos = np.arange(n - 1, len(s) - 1)
-            out.append(np.asarray(_gaps(top, x, jnp.asarray(pos),
-                                        jnp.asarray(s[pos + 1]),
-                                        _norm_key(c))))
+            row = {"gap": np.asarray(_gaps(top, x, jnp.asarray(pos),
+                                           jnp.asarray(s[pos + 1]),
+                                           _norm_key(c)))}
+            if m is not None:
+                row["router_margin"] = np.asarray(m)[pos]
+            out.append(row)
         return out
 
 

@@ -34,6 +34,10 @@ def parse(argv=None) -> argparse.Namespace:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--scored-out", default=None,
+                    help="also write every scored token's reference gap and "
+                         "router margin, request by request, to this JSON "
+                         "file (for setting limits; not part of a result)")
     return ap.parse_args(argv)
 
 
@@ -81,7 +85,13 @@ def main(argv=None) -> int:
     result = harness.run_cell(
         c, common.mix(w["traffic"]), common.cell(w["name"]),
         args.seed, args.seconds, trace, metrics, t_process=T_PROCESS,
-        trace_dir=str(ROOT / ".bench_trace" / w["name"]))
+        trace_dir=str(ROOT / ".bench_trace" / w["name"]),
+        keep_scored=args.scored_out is not None)
+    if args.scored_out is not None:
+        with open(args.scored_out, "w") as f:
+            json.dump(finite({"workload": w["name"], "seed": args.seed,
+                              "readings": result["readings"],
+                              "scored": result.pop("scored")}), f)
     for name, value in result["readings"].items():
         limit = result["checks"].get(name, {}).get("limit", "none: reported only")
         print(f"[bench] check {name}: {value} (limit {limit})",

@@ -8,11 +8,13 @@ import numpy as np
 
 
 def percentile(values: Iterable[float], q: float) -> Optional[float]:
-    """The q-th percentile (linear interpolation), None for no values."""
+    """The q-th percentile, taken as the nearest value at or above it (no
+    interpolation, so an infinite value reads as infinite and never as
+    NaN); None for no values."""
     v = np.asarray(list(values), np.float64)
     if v.size == 0:
         return None
-    return float(np.percentile(v, q))
+    return float(np.percentile(v, q, method="higher"))
 
 
 def ttft_s(rec) -> float:

@@ -6,11 +6,13 @@ the window has its first token; the cell's file gives the lengths of the
 lead-in and of the longest tail (``lead_s``, ``tail_s``). Each phase draws
 its lengths and its exponential (Poisson) inter-arrival gaps by stratified
 quantiles, ``F^-1((i + 0.5) / n)``, so every seed offers the same set of
-sizes and gaps, and arranges them in blocks (:func:`arrange`) so every
-stretch of the window gets its share of each; the seed only orders them and
-writes the prompt tokens. Two seeds therefore differ in arrangement, never
-in the amount of work, and a tail percentile does not swing with where one
-seed happens to cluster its long prompts.
+sizes and gaps; the seed orders them and writes the prompt tokens. The
+gaps are put in a free seeded order, so arrivals cluster as a Poisson
+process's do. The lengths are arranged in blocks (:func:`arrange`) so every
+stretch of the window gets its share of long prompts and long outputs:
+two seeds differ in order, never in the amount of work, and a tail
+percentile does not swing with where one seed happens to cluster its long
+prompts.
 """
 
 from __future__ import annotations
@@ -66,8 +68,8 @@ def arrange(values: np.ndarray, rng: np.random.Generator,
     """A seeded order of stratified ``values`` in which every run of
     ``block`` consecutive requests takes one value from each of ``block``
     strata (the sorted values cut into equal parts), so no stretch of the
-    window gets more than its share of long prompts, long outputs or short
-    gaps; the seed orders each stratum and each block."""
+    window gets more than its share of long prompts or long outputs; the
+    seed orders each stratum and each block."""
     strata = [rng.permutation(part)
               for part in np.array_split(np.sort(values), block)]
     out = []
@@ -91,7 +93,7 @@ def requests(mix: Dict, rate: float, seconds: float, vocab: int,
         p_len = arrange(lengths(mix["prompt_len"], n), rng)
         o_len = arrange(lengths(mix["output_len"], n), rng)
         # the first request of a phase is due at its opening
-        g = arrange(gaps(rate, n), rng)
+        g = rng.permutation(gaps(rate, n))
         due = t0 + np.concatenate([[0.0], np.cumsum(g)[:-1]])
         for i in range(n):
             out.append(Request(

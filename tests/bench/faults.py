@@ -12,11 +12,19 @@ from repro.serving import engine as E
 def plant(monkeypatch, fault: str) -> None:
     """``token``: every decode step's token for slot 0 is replaced by its
     successor, where it is produced. ``state``: the decode step hands back
-    the cache it was given, so no decoded token's K/V is ever kept."""
+    the cache it was given, so no decoded token's K/V is ever kept.
+    ``prefill``: the prefill reads its first token off the position before
+    the prompt's last, so only each request's first token is wrong."""
     real = E._jitted_steps
 
     def broken(cfg, kind, *a, **kw):
         prefill, chunked, suffix, decode, verify = real(cfg, kind, *a, **kw)
+        if fault == "prefill":
+            def early(step):
+                return None if step is None else (
+                    lambda p, t, li, *s: step(p, t, jnp.maximum(li - 1, 0),
+                                              *s))
+            return early(prefill), early(chunked), suffix, decode, verify
         if fault == "token":
             def decode_f(p, c, b):
                 tok, cache, lp = decode(p, c, b)

@@ -17,7 +17,8 @@ from bench import run as bench_run
 
 def smoke_run(family, monkeypatch, seed=2 ** 33 + 5, metrics=None):
     return harness.run_cell(
-        smoke_config(family, SMOKE_LIMITS[family]), SMOKE_MIX, SMOKE_CELL, seed, 2.0, False,
+        smoke_config(family, SMOKE_LIMITS[family]), SMOKE_MIX, SMOKE_CELL,
+        seed, 2.0, False,
         metrics if metrics is not None else common.benchmark()["end_to_end"],
         t_process=time.monotonic(), log=lambda s: None)
 
@@ -26,6 +27,10 @@ def smoke_run(family, monkeypatch, seed=2 ** 33 + 5, metrics=None):
 def test_smoke_cell_is_correct_and_reports_every_metric(family, monkeypatch):
     res = smoke_run(family, monkeypatch)
     assert res["correct"], res["checks"]
+    assert set(res["readings"]) == {
+        "lost", "short", "max_logit_gap", "mean_logit_gap",
+        "first_token_max_gap"} | ({"untied_mean_gap"} if family == "moe"
+                                  else set())
     assert res["failed"] == 0 and res["attempted"] == 8
     names = [m["name"] for m in common.benchmark()["end_to_end"]]
     assert list(res["metrics"]) == names
@@ -44,7 +49,7 @@ def test_result_line_schema(monkeypatch):
                                    "memory_peak_bytes"}
     assert line["device"]["platform"] == "cpu"
     assert set(line["readings"]) == {"lost", "short", "max_logit_gap",
-                                     "mean_logit_gap", "trimmed_mean_gap"}
+                                     "mean_logit_gap", "first_token_max_gap"}
     for name, v in line["checks"].items():
         assert set(v) == {"value", "limit"}, name
         assert v["value"] == line["readings"][name]

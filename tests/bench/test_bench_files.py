@@ -92,3 +92,14 @@ def test_command_refuses_in_a_bare_checkout(tmp_path):
     out = _cmd(tmp_path)
     assert out.returncode != 0 and out.stdout.strip() == ""
     assert json.loads((tmp_path / "BENCHMARK.json").read_text()) == bm
+
+
+WIDTH = re.compile(r"(_dim|_rank|_size|_factor)$|^num_experts_per_tok$")
+
+
+def test_reduced_cuts_no_width_and_departures_stand_apart():
+    for c in common.benchmark()["configs"]:
+        cfg = common.config(c["name"])
+        assert not [k for k in cfg["reduced"] if WIDTH.search(k)], c["name"]
+        # what the program cannot run as published is not a cut
+        assert not set(cfg.get("departs", {})) & set(cfg["reduced"])
