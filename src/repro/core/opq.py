@@ -233,9 +233,17 @@ class OPQ:
 
     @staticmethod
     def _default_executor(ins: Instruction, device):
-        args = [b.to_device(device) for b in ins.buffers]
-        out = ins.fn(*args)
-        return jax.block_until_ready(out)
+        """Inputs to the device (span ``opq.put``), the call (``opq.run``:
+        dispatch, or the trace and compile of a new shape) and the wait for
+        its results (``opq.block``)."""
+        # repro.serving imports this module, so the import waits for a call
+        from repro.serving.metrics import span
+        with span("opq.put"):
+            args = [b.to_device(device) for b in ins.buffers]
+        with span("opq.run"):
+            out = ins.fn(*args)
+        with span("opq.block"):
+            return jax.block_until_ready(out)
 
     def shutdown(self):
         self._pool.shutdown(wait=True)

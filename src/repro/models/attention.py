@@ -204,6 +204,7 @@ def _chunked_attention(q, k, v, causal: bool, chunk: int, unroll: bool = False,
     return o.astype(q.dtype)
 
 
+@L.scoped("attn")
 def attention(
     p: Dict,
     x: jax.Array,
@@ -241,6 +242,7 @@ def attention(
     return out
 
 
+@L.scoped("attn")
 def prefill_attention_with_kv(
     p: Dict,
     x: jax.Array,                 # (B, S, D) prompt activations
@@ -302,6 +304,7 @@ def prefill_attention_with_kv(
     return (out,) + entries
 
 
+@L.scoped("attn")
 def chunked_prefill_attention_with_kv(
     p: Dict,
     x: jax.Array,                 # (B, W, D) one prompt chunk's activations
@@ -425,6 +428,7 @@ def init_cache(cfg: ArchConfig, n_layers: int, batch: int, seq: int, dtype) -> D
     }
 
 
+@L.scoped("attn")
 def decode_attention(
     p: Dict,
     x: jax.Array,                 # (B, 1, D) current token
@@ -506,6 +510,7 @@ def decode_attention(
     return out, cache_k, cache_v
 
 
+@L.scoped("attn")
 def verify_decode_attention(
     p: Dict,
     x: jax.Array,                 # (B, W, D) window: last token + k draft tokens
@@ -572,6 +577,7 @@ def verify_decode_attention(
     return out, cache_k, cache_v
 
 
+@L.scoped("attn")
 def paged_verify_attention(
     p: Dict,
     x: jax.Array,                 # (B, W, D) window: last token + k draft tokens
@@ -639,6 +645,7 @@ def paged_verify_attention(
     return out, pool_k, pool_v
 
 
+@L.scoped("attn")
 def paged_decode_attention(
     p: Dict,
     x: jax.Array,                 # (B, 1, D) current token

@@ -3,10 +3,17 @@
 Everything is functional: params are nested dicts of jnp arrays; init_* builds
 them, apply functions consume them. Compute dtype is cfg.dtype (bf16 on TPU);
 master params and norm math stay f32.
+
+Named scopes: :func:`scoped` puts a layer's ops under one name in their op
+metadata (``attn``, ``mlp``, ``moe``, ``logits``), which the device trace
+carries as each op's ``tf_op`` path, e.g.
+``jit(decode_step)/while/body/closed_call/attn/dot_general``; scopes change
+no compiled program but its metadata.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -16,6 +23,20 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.core import tensorizer as tz
 from repro.distributed import sharding as shd
+
+
+def scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``, so
+    every caller gets the scope. A scope is made per call: one
+    ``named_scope`` used as a decorator keeps its entry state on itself,
+    which two threads tracing at once would share."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 def cdtype(cfg: ArchConfig):
@@ -142,6 +163,7 @@ def mlp_specs(cfg: ArchConfig) -> Dict:
     return {"wi": P(None, "model"), "wo": P("model", None)}
 
 
+@scoped("mlp")
 def apply_mlp(p: Dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
     h = pdot(x, p["wi"], cfg)
     if cfg.act == "swiglu":

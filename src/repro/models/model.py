@@ -254,6 +254,7 @@ def _embed_in(params, cfg: ArchConfig, batch: Dict) -> jax.Array:
     return shd.with_sharding(x, shd.batch_spec(None, None))
 
 
+@L.scoped("logits")
 def _logits(params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     x = L.apply_norm(params["final_ln"], x, cfg)
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -102,6 +102,7 @@ def _combine_local(y_buf, meta, T: int, D: int):
     return y.at[slot_token].add(y_slot)
 
 
+@L.scoped("moe")
 def apply_moe(p: Dict, x: jax.Array, cfg: ArchConfig, *,
               active: Optional[jax.Array] = None,
               row_isolated: bool = False) -> Tuple[jax.Array, jax.Array]:
@@ -125,6 +126,10 @@ def apply_moe(p: Dict, x: jax.Array, cfg: ArchConfig, *,
     exactly as they would alone, and exactly as the B=1 replay decode would
     have routed them (right-padding keeps pad tokens *after* the prompt in
     the cumsum, so they never shift real tokens either).
+
+    Named scopes under ``moe``: ``router`` (scores, dispatch into the expert
+    buffers, the aux loss), ``experts`` (the expert matmuls and the combine)
+    and ``shared`` (the shared experts).
     """
     mesh = shd.current_mesh()
     names = mesh.axis_names
@@ -160,35 +165,49 @@ def apply_moe(p: Dict, x: jax.Array, cfg: ArchConfig, *,
     def local_fn(xb, act, router, wi, wg, wo):
         Bl, Sl, _ = xb.shape
         xf = xb.reshape(Bl * Sl, D)
-        scores = (xf.astype(jnp.float32) @ router).astype(jnp.float32)   # (T, E)
-        e_offset = (jax.lax.axis_index("model") * E_loc) if has_model else 0
-        if row_isolated:
-            # per-row dispatch: buffers (Bl, E_loc, C, D), cumsum within a row
-            x_buf, meta = jax.vmap(
-                lambda xr, sr: _dispatch_local(
-                    xr, sr, E, E_loc, e_offset, topk, capacity, cfg)
-            )(xb, scores.reshape(Bl, Sl, E))
-            h = jnp.einsum("becd,edf->becf", x_buf, wi.astype(xb.dtype))
-            h = jax.nn.silu(h) * jnp.einsum("becd,edf->becf", x_buf, wg.astype(xb.dtype))
-            y_buf = jnp.einsum("becf,efd->becd", h, wo.astype(xb.dtype))
-            y = jax.vmap(lambda yb, m: _combine_local(yb, m, Sl, D))(y_buf, meta)
-            y = y.reshape(Bl * Sl, D)
-        else:
-            token_valid = jnp.broadcast_to(act[:, None], (Bl, Sl)).reshape(-1)
-            x_buf, meta = _dispatch_local(xf, scores, E, E_loc, e_offset, topk,
-                                          capacity, cfg, token_valid=token_valid)
-            h = jnp.einsum("ecd,edf->ecf", x_buf, wi.astype(xb.dtype))
-            h = jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", x_buf, wg.astype(xb.dtype))
-            y_buf = jnp.einsum("ecf,efd->ecd", h, wo.astype(xb.dtype))
-            y = _combine_local(y_buf, meta, Bl * Sl, D)
-        if has_model:
-            y = jax.lax.psum(y, "model")
-        # Switch-style load-balance aux: E * sum_e f_e * p_e  (replicated over model)
-        probs = jax.nn.softmax(scores, axis=-1)
-        _, ids = jax.lax.top_k(scores, topk)
-        f = jnp.mean(jax.nn.one_hot(ids, E, dtype=jnp.float32).sum(axis=1), axis=0)
-        pmean = jnp.mean(probs, axis=0)
-        aux = E * jnp.sum(f * pmean)
+        with jax.named_scope("router"):
+            scores = (xf.astype(jnp.float32) @ router).astype(
+                jnp.float32)                                        # (T, E)
+            e_offset = ((jax.lax.axis_index("model") * E_loc) if has_model
+                        else 0)
+            if row_isolated:
+                # per-row dispatch: buffers (Bl, E_loc, C, D), cumsum in a row
+                x_buf, meta = jax.vmap(
+                    lambda xr, sr: _dispatch_local(
+                        xr, sr, E, E_loc, e_offset, topk, capacity, cfg)
+                )(xb, scores.reshape(Bl, Sl, E))
+            else:
+                token_valid = jnp.broadcast_to(act[:, None],
+                                               (Bl, Sl)).reshape(-1)
+                x_buf, meta = _dispatch_local(
+                    xf, scores, E, E_loc, e_offset, topk, capacity, cfg,
+                    token_valid=token_valid)
+        with jax.named_scope("experts"):
+            if row_isolated:
+                h = jnp.einsum("becd,edf->becf", x_buf, wi.astype(xb.dtype))
+                h = jax.nn.silu(h) * jnp.einsum("becd,edf->becf", x_buf,
+                                                wg.astype(xb.dtype))
+                y_buf = jnp.einsum("becf,efd->becd", h, wo.astype(xb.dtype))
+                y = jax.vmap(lambda yb, m: _combine_local(yb, m, Sl, D))(
+                    y_buf, meta)
+                y = y.reshape(Bl * Sl, D)
+            else:
+                h = jnp.einsum("ecd,edf->ecf", x_buf, wi.astype(xb.dtype))
+                h = jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", x_buf,
+                                                wg.astype(xb.dtype))
+                y_buf = jnp.einsum("ecf,efd->ecd", h, wo.astype(xb.dtype))
+                y = _combine_local(y_buf, meta, Bl * Sl, D)
+            if has_model:
+                y = jax.lax.psum(y, "model")
+        with jax.named_scope("router"):
+            # Switch-style load-balance aux: E * sum_e f_e * p_e
+            # (replicated over model)
+            probs = jax.nn.softmax(scores, axis=-1)
+            _, ids = jax.lax.top_k(scores, topk)
+            f = jnp.mean(jax.nn.one_hot(ids, E, dtype=jnp.float32).sum(axis=1),
+                         axis=0)
+            pmean = jnp.mean(probs, axis=0)
+            aux = E * jnp.sum(f * pmean)
         return y.reshape(Bl, Sl, D), aux[None]
 
     in_specs = (
@@ -206,5 +225,6 @@ def apply_moe(p: Dict, x: jax.Array, cfg: ArchConfig, *,
     )(x, active, router, wi, wg, wo)
 
     if cfg.n_shared_experts:
-        y = y + L.apply_mlp(p["shared"], x, cfg)
+        with jax.named_scope("shared"):
+            y = y + L.apply_mlp(p["shared"], x, cfg)
     return y, jnp.mean(aux)

@@ -72,7 +72,8 @@ from repro.configs.base import ArchConfig
 from repro.core.opq import OPQ, Buffer
 from repro.distributed import sharding as shd
 from repro.models import steps as ST
-from repro.serving.metrics import EngineMetrics, RequestMetrics, now
+from repro.serving.metrics import (EngineMetrics, RequestMetrics, now, span,
+                                   trace_hooks)
 from repro.serving.sampling import (
     GREEDY, TOP_LOGPROBS, SamplingParams, stack_params, stop_match,
 )
@@ -388,6 +389,7 @@ class Engine:
             raise ValueError(
                 f"serving engine supports token-input dense/moe/ssm/hybrid "
                 f"archs, got family={cfg.family} input_mode={cfg.input_mode}")
+        trace_hooks()
         self.cfg = cfg
         self.ecfg = engine_cfg or EngineConfig()
         # one device per engine: its params, cache and every step it traces
@@ -784,20 +786,20 @@ class Engine:
                     Buffer(last), flags=f"draft_prefill/{bucket}")
             pending.append((slot, req, fut, dfut))
         for slot, req, fut, dfut in pending:
-            t0 = now()
-            first, kv, lp = fut.result()
-            tok = int(np.asarray(first)[0])
-            self.metrics.prefill_wait_s += now() - t0
+            with span("engine.prefill_wait", self.metrics, "prefill_wait_s"):
+                first, kv, lp = fut.result()
+                tok = int(np.asarray(first)[0])
             self.metrics.prefill_batches += 1
             self.metrics.prefill_tokens += len(req.prompt)
-            t0 = now()
-            self._seed_admitted(slot, req, kv)
-            if dfut is not None:
-                # draft first token discarded — the TARGET's prefill token is
-                # the emitted one; the draft only needed its cache seeded
-                _, dkv = dfut.result()
-                self.draft_store.write_slots([slot], dkv, [len(req.prompt)])
-            self.metrics.seed_write_s += now() - t0
+            with span("engine.seed", self.metrics, "seed_write_s"):
+                self._seed_admitted(slot, req, kv)
+                if dfut is not None:
+                    # draft first token discarded — the TARGET's prefill
+                    # token is the emitted one; the draft only needed its
+                    # cache seeded
+                    _, dkv = dfut.result()
+                    self.draft_store.write_slots([slot], dkv,
+                                                 [len(req.prompt)])
             req.state = RequestState.RUNNING
             req.tokens.append(tok)
             req.token_ts.append(now())
@@ -843,15 +845,29 @@ class Engine:
         self.store.write_slots([slot], kv, [len(req.prompt)])
 
     def _decode_once(self) -> None:
-        toks, active = self.scheduler.decode_batch()
-        next_tok, cache, lp = self._dispatch(
-            lambda p, c, b: self._decode(p, c, b),
-            self._params_buf,
-            self._resident(self.store.decode_cache(), "kv-cache"),
-            Buffer({"tokens": toks, "active": active,
-                    "sampling": self._sampling_batch()},
-                   name="decode-tokens"),
-            flags="decode")
+        """One batched decode step, in three host spans inside
+        ``engine.decode``: ``prepare`` (batch, sampling params, presence
+        copy, buffers), ``wait`` (dispatch until the result is in hand) and
+        ``emit`` (cache swap, host copy of the tokens, the slot loop,
+        retires)."""
+        with span("engine.decode"):
+            with span("engine.decode.prepare"):
+                toks, active = self.scheduler.decode_batch()
+                bufs = (self._params_buf,
+                        self._resident(self.store.decode_cache(), "kv-cache"),
+                        Buffer({"tokens": toks, "active": active,
+                                "sampling": self._sampling_batch()},
+                               name="decode-tokens"))
+            with span("engine.decode.wait"):
+                next_tok, cache, lp = self._dispatch(
+                    lambda p, c, b: self._decode(p, c, b), *bufs,
+                    flags="decode")
+            with span("engine.decode.emit"):
+                self._emit_decoded(next_tok, cache, lp)
+
+    def _emit_decoded(self, next_tok, cache, lp) -> None:
+        """Swap in the step's cache, copy its tokens to the host and hand
+        each active slot its token; retire the finished."""
         self.store.swap(cache)
         self.metrics.decode_steps += 1
         next_np = np.asarray(next_tok)
@@ -884,17 +900,31 @@ class Engine:
         so a bad draft costs speed, never correctness. Rejected window
         positions are scrubbed from BOTH caches before any retire: future
         verify horizons reach them, and the retire-time row bits must equal
-        plain decode's (the cache-bit half of the invariant)."""
+        plain decode's (the cache-bit half of the invariant). Host spans as
+        in :meth:`_decode_once`; ``wait`` holds the draft steps and the
+        verify."""
+        with span("engine.decode"):
+            with span("engine.decode.prepare"):
+                toks, active = self.scheduler.decode_batch()
+                # window column 0 is each slot's last emitted token; columns
+                # 1..k the draft's chained proposals
+                window = np.zeros((self.ecfg.max_slots, self.ecfg.spec_k + 1),
+                                  np.int32)
+                window[:, 0] = toks[:, 0]
+                snapshots = ([self.draft_store.decode_cache()]
+                             if self._draft_recurrent else None)
+            with span("engine.decode.wait"):
+                greedy, cache, lp = self._spec_propose_verify(
+                    toks, active, window, snapshots)
+            with span("engine.decode.emit"):
+                self._spec_accept(window, snapshots, greedy, cache, lp)
+
+    def _spec_propose_verify(self, toks, active, window, snapshots):
+        """The draft's k+1 chained steps (filling ``window`` and
+        ``snapshots``), then the target's one wide verify forward."""
         k = self.ecfg.spec_k
         W = k + 1
         n = self.ecfg.max_slots
-        toks, active = self.scheduler.decode_batch()
-        # ---- draft: propose. Window column 0 is each slot's last emitted
-        # token; columns 1..k the draft's chained proposals.
-        window = np.zeros((n, W), np.int32)
-        window[:, 0] = toks[:, 0]
-        snapshots = ([self.draft_store.decode_cache()]
-                     if self._draft_recurrent else None)
         cur = toks
         for i in range(W):
             nxt, dcache = self._dispatch(
@@ -912,12 +942,19 @@ class Engine:
                 window[:, i + 1] = nxt_np
             cur = nxt_np.reshape(n, 1)
         # ---- verify: one wide target forward for the whole batch
-        greedy, cache, lp = self._dispatch(
+        return self._dispatch(
             lambda p, c, b: self._verify(p, c, b),
             self._params_buf,
             self._resident(self.store.decode_cache(), "kv-cache"),
             Buffer({"tokens": window, "active": active}, name="verify-window"),
             flags="verify")
+
+    def _spec_accept(self, window, snapshots, greedy, cache, lp) -> None:
+        """Each slot's acceptance, the emitted tokens, the rollback of both
+        caches and the retires of one draft-verify round."""
+        k = self.ecfg.spec_k
+        W = k + 1
+        n = self.ecfg.max_slots
         self.store.swap_window(cache, W)
         self.metrics.decode_steps += 1
         self.metrics.spec_rounds += 1
@@ -1216,34 +1253,38 @@ class Engine:
 
     @_placed
     def step(self) -> None:
-        """One engine iteration: join waiting requests into free slots, then
-        one batched decode step for whatever is in flight."""
-        admitted = self._admit()
-        if (admitted == 0 and not self.scheduler.active
-                and self.scheduler.waiting):
-            # zero-progress state: the queue head was deferred by the store
-            # lease while NOTHING is in flight — no retire can ever free
-            # capacity, so every further step would be an identical no-op.
-            # (fits() should have bounced such a request at submit; this
-            # guards the submit-time-reject vs lease-time-defer line against
-            # drift, which previously burned max_steps idle iterations.)
-            head = self.scheduler.waiting[0]
-            raise RuntimeError(
-                f"admission livelock: request {head.id} "
-                f"(prompt={len(head.prompt)} tok, "
-                f"max_new_tokens={head.max_new_tokens}) was deferred by the "
-                f"{self.store.kind} store's lease with zero active slots — "
-                f"no retire can free capacity for it; "
-                f"store: {self.store.memory_stats()}")
-        # occupancy sampled before the decode's retires, so slots busy this
-        # step count even when their request finishes in it
-        n_active = self.scheduler.n_active
-        if n_active:
-            if self.ecfg.speculative:
-                self._spec_decode_once()
-            else:
-                self._decode_once()
-        self.metrics.observe_step(self.scheduler.queue_depth, n_active)
+        """One engine iteration (span ``engine.step``): join waiting requests
+        into free slots (``engine.admit``), then one batched decode step for
+        whatever is in flight (``engine.decode``)."""
+        with span("engine.step"):
+            with span("engine.admit"):
+                admitted = self._admit()
+            if (admitted == 0 and not self.scheduler.active
+                    and self.scheduler.waiting):
+                # zero-progress state: the queue head was deferred by the
+                # store lease while NOTHING is in flight — no retire can
+                # ever free capacity, so every further step would be an
+                # identical no-op. (fits() should have bounced such a
+                # request at submit; this guards the submit-time-reject vs
+                # lease-time-defer line against drift, which previously
+                # burned max_steps idle iterations.)
+                head = self.scheduler.waiting[0]
+                raise RuntimeError(
+                    f"admission livelock: request {head.id} "
+                    f"(prompt={len(head.prompt)} tok, "
+                    f"max_new_tokens={head.max_new_tokens}) was deferred by "
+                    f"the {self.store.kind} store's lease with zero active "
+                    f"slots — no retire can free capacity for it; "
+                    f"store: {self.store.memory_stats()}")
+            # occupancy sampled before the decode's retires, so slots busy
+            # this step count even when their request finishes in it
+            n_active = self.scheduler.n_active
+            if n_active:
+                if self.ecfg.speculative:
+                    self._spec_decode_once()
+                else:
+                    self._decode_once()
+            self.metrics.observe_step(self.scheduler.queue_depth, n_active)
 
     def has_work(self) -> bool:
         return self.scheduler.has_work()

@@ -9,6 +9,13 @@ halves as prefill_wait_s / seed_write_s. Engine counters are designed to *reconc
 ``tokens_generated`` must equal the sum of every completed/active request's
 ``n_generated`` (asserted in tests/test_serving.py).
 
+Host spans: :class:`span` marks a layer of the serving path on the JAX
+profiler's trace (``router.*``, ``engine.*``, ``opq.*``, ``python.gc``), on
+the same clock as the device's operations, so a trace says what the host
+was doing in every gap of the device; ``bench/spans.py`` reads them. A span
+can also feed an engine counter, which is how ``prefill_wait_s`` and
+``seed_write_s`` are timed.
+
 Cache-memory telemetry comes from ``SlotStore.memory_stats()`` (bytes per
 backend, block occupancy for the paged store) — surfaced through
 ``Engine.stats()["cache"]`` and rendered by :func:`format_memory_stats` in
@@ -19,12 +26,77 @@ lease refusals (paged block-pool backpressure).
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from typing import Dict, Optional
+
+import jax
+from jax.profiler import TraceAnnotation
 
 
 def now() -> float:
     return time.monotonic()
+
+
+def span(name: str, metrics=None, field: str = ""):
+    """``with span("engine.decode"):`` — one named host span on the
+    profiler's trace (a ``jax.profiler.TraceAnnotation``). Outside a trace
+    it records nothing and costs a few hundred ns. Given ``metrics`` and
+    ``field``, the span's wall time is also added to that counter
+    (``span("engine.seed", self.metrics, "seed_write_s")``)."""
+    if metrics is None:
+        return TraceAnnotation(name)
+    return _CountedSpan(name, metrics, field)
+
+
+class _CountedSpan:
+    """A span that also adds its wall time to ``metrics.<field>``."""
+
+    __slots__ = ("_ann", "_metrics", "_field", "_t0")
+
+    def __init__(self, name: str, metrics, field: str):
+        self._ann = TraceAnnotation(name)
+        self._metrics, self._field = metrics, field
+
+    def __enter__(self):
+        self._t0 = now()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        setattr(self._metrics, self._field,
+                getattr(self._metrics, self._field) + now() - self._t0)
+
+
+class _GcSpans:
+    """A ``python.gc`` span over each collection of the garbage collector,
+    opened and closed from ``gc.callbacks`` (start and stop run on the
+    collecting thread, and collections never overlap)."""
+
+    def __init__(self):
+        self._open: Optional[TraceAnnotation] = None
+
+    def __call__(self, phase: str, info: Dict) -> None:
+        if phase == "start":
+            self._open = TraceAnnotation("python.gc")
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+def trace_hooks() -> None:
+    """What a trace of the serving path relies on, process-wide and made
+    once however often this is called: a ``python.gc`` span over every
+    garbage collection, and op metadata in the key of JAX's persistent
+    compilation cache. Without the latter an executable loaded from the
+    cache keeps the metadata (the named scopes) of whatever code compiled
+    it first, since scopes leave the program itself unchanged, and a device
+    trace shows those in place of the running code's."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if not any(isinstance(cb, _GcSpans) for cb in gc.callbacks):
+        gc.callbacks.append(_GcSpans())
 
 
 @dataclasses.dataclass
@@ -132,8 +204,10 @@ class EngineMetrics:
                                                # shipped block payload — zero
                                                # prefill dispatches each
     imported_blocks: int = 0                   # pool blocks adopted verbatim
-    prefill_wait_s: float = 0.0                # wall time blocked on prefill forwards
-    seed_write_s: float = 0.0                  # wall time in batched slot writes
+    prefill_wait_s: float = 0.0                # wall time blocked on prefill
+                                               # forwards (engine.prefill_wait)
+    seed_write_s: float = 0.0                  # wall time in batched slot
+                                               # writes (engine.seed)
     steps: int = 0                             # engine iterations observed
     queue_depth_sum: int = 0                   # for mean queue depth
     occupancy_sum: int = 0                     # active slots summed per step
@@ -226,7 +300,6 @@ class TransportMetrics:
             "retries": self.retries,
             "errors": self.errors,
             "rpc_wait_s": self.rpc_wait_s,
-            "mean_rpc_us": 1e6 * self.rpc_wait_s / max(self.rpcs, 1),
         }
 
 

@@ -80,7 +80,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.serving.metrics import now
+from repro.serving.metrics import now, span
 from repro.serving.sampling import SamplingParams
 from repro.serving.transport import (
     EngineConfig, HostTransport, QueueFull, TransportError,
@@ -633,20 +633,23 @@ class Router:
         free-run), harvest new tokens and completions, and retry orphaned
         requests against recovered capacity. Draining hosts are pumped too —
         they must finish what they hold."""
-        if self._orphans:
-            pending, self._orphans = self._orphans, []
-            for rreq in pending:
-                if not self._readmit(rreq):
-                    self._orphans.append(rreq)
-        for host in range(self.rcfg.n_hosts):
-            if host in self._lost:
-                continue
-            self._guard(host, self.transports[host].pump)
-            if host in self._lost:
-                continue
-            self._harvest(host)
-        if self.rcfg.roles is not None:
-            self._disagg_handoff()
+        with span("router.step"):
+            if self._orphans:
+                pending, self._orphans = self._orphans, []
+                for rreq in pending:
+                    if not self._readmit(rreq):
+                        self._orphans.append(rreq)
+            for host in range(self.rcfg.n_hosts):
+                if host in self._lost:
+                    continue
+                with span("router.pump"):
+                    self._guard(host, self.transports[host].pump)
+                if host in self._lost:
+                    continue
+                with span("router.poll"):
+                    self._harvest(host)
+            if self.rcfg.roles is not None:
+                self._disagg_handoff()
 
     def _disagg_handoff(self) -> None:
         """Move prefilled streams from prefill-role hosts onto decode-role

@@ -143,11 +143,18 @@ def sample_tokens(logits: jnp.ndarray, sp: Dict[str, jnp.ndarray],
     temperature, top-k, top-p, then Gumbel-max with counter-derived noise.
     All rows run through one executable; the mix is masked, not branched
     per-row.
+
+    Traced under the named scope ``sample``, but for the greedy argmax: the
+    greedy branch of the ``cond`` below passes it through, and XLA names
+    that branch's parameter after the scope of the value, so a scope on it
+    would change the compiled program beyond its metadata.
     """
-    logits = logits.astype(jnp.float32)
+    with jax.named_scope("sample"):
+        logits = logits.astype(jnp.float32)
     B, V = logits.shape
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+    @jax.named_scope("sample")
     def sampled(_):
         # Repetition penalty (CTRL-style) over the presence mask.
         rep = sp["rep_penalty"][:, None]
@@ -175,18 +182,21 @@ def sample_tokens(logits: jnp.ndarray, sp: Dict[str, jnp.ndarray],
 
     # Skip the whole sampled pipeline when every row is greedy (the common
     # serving default pays nothing).
-    tok = jax.lax.cond(jnp.any(~sp["greedy"]), sampled,
-                       lambda _: greedy_tok, operand=None)
-    return jnp.where(sp["greedy"], greedy_tok, tok)
+    with jax.named_scope("sample"):
+        tok = jax.lax.cond(jnp.any(~sp["greedy"]), sampled,
+                           lambda _: greedy_tok, operand=None)
+        return jnp.where(sp["greedy"], greedy_tok, tok)
 
 
 def choose_tokens(row: jnp.ndarray, sampling: Optional[Dict[str, jnp.ndarray]],
                   positions) -> jnp.ndarray:
     """Logits row -> token, for the step builders: greedy argmax when no
     sampling state is threaded (legacy/test callers), the batched sampler
-    otherwise. ``positions`` may be scalar (broadcast over the batch)."""
+    otherwise. ``positions`` may be scalar (broadcast over the batch).
+    Traced under the named scope ``sample``."""
     if sampling is None:
-        return jnp.argmax(row, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            return jnp.argmax(row, axis=-1).astype(jnp.int32)
     positions = jnp.asarray(positions, jnp.int32)
     if positions.ndim == 0:
         positions = jnp.broadcast_to(positions, (row.shape[0],))
@@ -204,17 +214,18 @@ def logprob_info(row: jnp.ndarray, chosen: jnp.ndarray,
     Padded vocab columns are masked to -inf BEFORE the softmax so the
     distribution is over the real vocabulary (pad logits are unspecified).
     Returns {"lp": (...,) f32, "top_ids": (..., K) i32, "top_lps":
-    (..., K) f32}.
+    (..., K) f32}. Traced under the named scope ``sample``.
     """
-    row = row.astype(jnp.float32)
-    V = row.shape[-1]
-    real = jnp.arange(V) < vocab
-    lp = jax.nn.log_softmax(jnp.where(real, row, -jnp.inf), axis=-1)
-    chosen_lp = jnp.take_along_axis(
-        lp, chosen[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    top_lps, top_ids = jax.lax.top_k(lp, TOP_LOGPROBS)
-    return {"lp": chosen_lp, "top_ids": top_ids.astype(jnp.int32),
-            "top_lps": top_lps}
+    with jax.named_scope("sample"):
+        row = row.astype(jnp.float32)
+        V = row.shape[-1]
+        real = jnp.arange(V) < vocab
+        lp = jax.nn.log_softmax(jnp.where(real, row, -jnp.inf), axis=-1)
+        chosen_lp = jnp.take_along_axis(
+            lp, chosen[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        top_lps, top_ids = jax.lax.top_k(lp, TOP_LOGPROBS)
+        return {"lp": chosen_lp, "top_ids": top_ids.astype(jnp.int32),
+                "top_lps": top_lps}
 
 
 def sampling_to_wire(sp: Optional[SamplingParams]) -> Optional[Dict]:
