@@ -117,6 +117,48 @@ def _expand_kv(k: jax.Array, n_heads: int) -> jax.Array:
     return jnp.repeat(k, rep, axis=2) if rep > 1 else k
 
 
+def _dequant(c: jax.Array, scale: jax.Array, dtype) -> jax.Array:
+    """int8 KV entries (..., KV, hd) x per-token/per-head scales (..., KV)
+    back to the activation dtype, as every cache-attention path reads them."""
+    return (c.astype(jnp.float32) * scale[..., None]).astype(dtype)
+
+
+def _table_rows(pool: jax.Array, tables: jax.Array) -> jax.Array:
+    """One layer's pool (n_blocks, block_size, ...) read through per-slot
+    block tables (B, MB) as contiguous rows (B, MB*block_size, ...)."""
+    B, MB = tables.shape
+    rows = jnp.take(pool, tables.reshape(-1), axis=0)
+    return rows.reshape(B, MB * pool.shape[1], *pool.shape[2:])
+
+
+def _cached_attend(q: jax.Array, k: jax.Array, v: jax.Array,
+                   valid: jax.Array, hd: int) -> jax.Array:
+    """Queries (B, Sq, H, hd) over cache rows (B, S, KV, hd) under the mask
+    ``valid`` (B or 1, Sq or 1, S); returns the float32 output (B, Sq, H, hd).
+
+    The one contraction every cache-attention path shares, so decode,
+    verify, paged and fused/chunked prefill agree bit for bit by
+    construction. Grouped: each KV head is contracted against its
+    G = H // KV query heads straight from the cache dtype, with float32
+    accumulation (a bf16 x bf16 product is exact in float32), so no H-head
+    or float32 copy of the cache is made. The float32 probabilities meet
+    the cache-dtype values in one mixed-dtype dot at HIGHEST precision,
+    which keeps them float32 on the TPU. (Splitting them into bf16 pieces
+    by hand does not: XLA's TPU compiler may drop the f32 -> bf16 -> f32
+    round trip that makes the pieces, leaving bf16 probabilities.)"""
+    B, Sq, H, _ = q.shape
+    KV = k.shape[2]
+    s = jnp.einsum("bqkgd,bskd->bkgqs", q.reshape(B, Sq, KV, H // KV, hd), k,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(valid[:, None, None], s * (hd ** -0.5), NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    # (B, KV, G, Sq, S) x (B, S, KV, hd): contract S, batch B and KV
+    o = jax.lax.dot_general(w, v, (((4,), (1,)), ((0, 1), (0, 2))),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    return o.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd)
+
+
 def _quantize_kv(k_new: jax.Array, v_new: jax.Array):
     """Tensorizer int8 KV-cache quantization: per-token / per-head amax scales
     (exact per-position calibration — no cross-step rescaling). The SINGLE
@@ -261,8 +303,8 @@ def prefill_attention_with_kv(
     cache layout and scatter into serving slot rows.
 
     The math deliberately mirrors :func:`decode_attention` bit-for-bit rather
-    than reusing :func:`attention`'s plain/chunked paths: scores and the value
-    contraction run in f32 against the *cache-dtype* K/V (int8 entries are
+    than reusing :func:`attention`'s plain/chunked paths: both run
+    :func:`_cached_attend` against the *cache-dtype* K/V (int8 entries are
     quantized with the same per-token/per-head amax scales and dequantized
     before use, exactly as decode reads them back). That makes a cache seeded
     from these entries continue decoding with the identical token stream the
@@ -281,25 +323,14 @@ def prefill_attention_with_kv(
     q, k_new, v_new = _project_qkv(p, x, cfg, positions, positions3)
     if int8_kv:
         k_q, v_q, k_sc, v_sc = _quantize_kv(k_new, v_new)
-        k_full = k_q.astype(jnp.float32) * k_sc[..., None]
-        v_full = v_q.astype(jnp.float32) * v_sc[..., None]
-        k = _expand_kv(k_full.astype(x.dtype), cfg.n_heads)
-        v = _expand_kv(v_full.astype(x.dtype), cfg.n_heads)
+        k, v = _dequant(k_q, k_sc, x.dtype), _dequant(v_q, v_sc, x.dtype)
         entries: Tuple[jax.Array, ...] = (k_q, v_q, k_sc, v_sc)
     else:
-        cache_dt = L.cdtype(cfg)
-        k_c = k_new.astype(cache_dt)
-        v_c = v_new.astype(cache_dt)
-        k = _expand_kv(k_c, cfg.n_heads)
-        v = _expand_kv(v_c, cfg.n_heads)
-        entries = (k_c, v_c)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
-    s = s * (cfg.hd ** -0.5)
-    qpos = jnp.arange(S)[:, None]
-    kpos = jnp.arange(S)[None, :]
-    s = jnp.where((kpos <= qpos)[None, None], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32)).astype(x.dtype)
+        k = k_new.astype(L.cdtype(cfg))
+        v = v_new.astype(L.cdtype(cfg))
+        entries = (k, v)
+    valid = (jnp.arange(S)[None, :] <= jnp.arange(S)[:, None])[None]
+    o = _cached_attend(q, k, v, valid, cfg.hd).astype(x.dtype)
     out = L.pdot(o.reshape(B, S, cfg.n_heads * cfg.hd), p["wo"], cfg)
     return (out,) + entries
 
@@ -347,25 +378,16 @@ def chunked_prefill_attention_with_kv(
         v_acc = jax.lax.dynamic_update_slice(v_acc, v_q, (0, chunk_start, 0, 0))
         k_sc_acc = jax.lax.dynamic_update_slice(k_sc_acc, k_sc, (0, chunk_start, 0))
         v_sc_acc = jax.lax.dynamic_update_slice(v_sc_acc, v_sc, (0, chunk_start, 0))
-        k_full = k_acc.astype(jnp.float32) * k_sc_acc[..., None]
-        v_full = v_acc.astype(jnp.float32) * v_sc_acc[..., None]
-        k = _expand_kv(k_full.astype(x.dtype), cfg.n_heads)
-        v = _expand_kv(v_full.astype(x.dtype), cfg.n_heads)
+        k, v = _dequant(k_acc, k_sc_acc, x.dtype), _dequant(v_acc, v_sc_acc, x.dtype)
     else:
         cache_dt = L.cdtype(cfg)
         k_acc = jax.lax.dynamic_update_slice(
             k_acc, k_new.astype(cache_dt), (0, chunk_start, 0, 0))
         v_acc = jax.lax.dynamic_update_slice(
             v_acc, v_new.astype(cache_dt), (0, chunk_start, 0, 0))
-        k = _expand_kv(k_acc, cfg.n_heads)
-        v = _expand_kv(v_acc, cfg.n_heads)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
-    s = s * (cfg.hd ** -0.5)
-    qpos = chunk_start + jnp.arange(W)[:, None]
-    kpos = jnp.arange(S)[None, :]
-    s = jnp.where((kpos <= qpos)[None, None], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32)).astype(x.dtype)
+        k, v = k_acc, v_acc
+    valid = (jnp.arange(S)[None, :] <= chunk_start + jnp.arange(W)[:, None])[None]
+    o = _cached_attend(q, k, v, valid, cfg.hd).astype(x.dtype)
     out = L.pdot(o.reshape(B, W, cfg.n_heads * cfg.hd), p["wo"], cfg)
     if int8_kv:
         return out, k_acc, v_acc, k_sc_acc, v_sc_acc
@@ -482,10 +504,7 @@ def decode_attention(
             cache_v = jax.lax.dynamic_update_slice(cache_v, v_q, (0, index, 0, 0))
             ks = jax.lax.dynamic_update_slice(ks, k_sc, (0, index, 0))
             vs = jax.lax.dynamic_update_slice(vs, v_sc, (0, index, 0))
-        k_full = cache_k.astype(jnp.float32) * ks[..., None]
-        v_full = cache_v.astype(jnp.float32) * vs[..., None]
-        k = _expand_kv(k_full.astype(x.dtype), cfg.n_heads)
-        v = _expand_kv(v_full.astype(x.dtype), cfg.n_heads)
+        k, v = _dequant(cache_k, ks, x.dtype), _dequant(cache_v, vs, x.dtype)
     else:
         if update_cache and per_row:
             cache_k = cache_k.at[rows, index].set(
@@ -495,15 +514,10 @@ def decode_attention(
         elif update_cache:
             cache_k = jax.lax.dynamic_update_slice(cache_k, k_new.astype(cache_k.dtype), (0, index, 0, 0))
             cache_v = jax.lax.dynamic_update_slice(cache_v, v_new.astype(cache_v.dtype), (0, index, 0, 0))
-        k = _expand_kv(cache_k, cfg.n_heads)
-        v = _expand_kv(cache_v, cfg.n_heads)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
-    s = s * (cfg.hd ** -0.5)
-    horizon = index[:, None, None, None] if per_row else index
-    valid = jnp.arange(S)[None, None, None, :] <= horizon     # causal: <= current
-    s = jnp.where(valid, s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32)).astype(x.dtype)
+        k, v = cache_k, cache_v
+    horizon = index[:, None, None] if per_row else index
+    valid = jnp.arange(S)[None, None, :] <= horizon           # causal: <= current
+    o = _cached_attend(q, k, v, valid, cfg.hd).astype(x.dtype)
     out = L.pdot(o.reshape(B, 1, cfg.n_heads * cfg.hd), p["wo"], cfg)
     if int8_cache:
         return out, cache_k, cache_v, ks, vs
@@ -553,24 +567,16 @@ def verify_decode_attention(
         cache_v = cache_v.at[rows[:, None], positions].set(v_q, mode="drop")
         ks = ks.at[rows[:, None], positions].set(k_sc, mode="drop")
         vs = vs.at[rows[:, None], positions].set(v_sc, mode="drop")
-        k_full = cache_k.astype(jnp.float32) * ks[..., None]
-        v_full = cache_v.astype(jnp.float32) * vs[..., None]
-        k = _expand_kv(k_full.astype(x.dtype), cfg.n_heads)
-        v = _expand_kv(v_full.astype(x.dtype), cfg.n_heads)
+        k, v = _dequant(cache_k, ks, x.dtype), _dequant(cache_v, vs, x.dtype)
     else:
         cache_k = cache_k.at[rows[:, None], positions].set(
             k_new.astype(cache_k.dtype), mode="drop")
         cache_v = cache_v.at[rows[:, None], positions].set(
             v_new.astype(cache_v.dtype), mode="drop")
-        k = _expand_kv(cache_k, cfg.n_heads)
-        v = _expand_kv(cache_v, cfg.n_heads)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
-    s = s * (cfg.hd ** -0.5)
+        k, v = cache_k, cache_v
     # per-query causal horizon: query j sees kpos <= index + j
-    valid = jnp.arange(S)[None, None, None, :] <= positions[:, None, :, None]
-    s = jnp.where(valid, s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32)).astype(x.dtype)
+    valid = jnp.arange(S)[None, None, :] <= positions[:, :, None]
+    o = _cached_attend(q, k, v, valid, cfg.hd).astype(x.dtype)
     out = L.pdot(o.reshape(B, W, cfg.n_heads * cfg.hd), p["wo"], cfg)
     if int8_cache:
         return out, cache_k, cache_v, ks, vs
@@ -620,25 +626,12 @@ def paged_verify_attention(
     else:
         pool_k = pool_k.at[phys, off].set(k_new.astype(pool_k.dtype))
         pool_v = pool_v.at[phys, off].set(v_new.astype(pool_v.dtype))
-    flat = tables.reshape(-1)
-    k_rows = jnp.take(pool_k, flat, axis=0).reshape(B, S, *pool_k.shape[2:])
-    v_rows = jnp.take(pool_v, flat, axis=0).reshape(B, S, *pool_v.shape[2:])
+    k, v = _table_rows(pool_k, tables), _table_rows(pool_v, tables)
     if int8_cache:
-        ks = jnp.take(pks, flat, axis=0).reshape(B, S, *pks.shape[2:])
-        vs = jnp.take(pvs, flat, axis=0).reshape(B, S, *pvs.shape[2:])
-        k_full = k_rows.astype(jnp.float32) * ks[..., None]
-        v_full = v_rows.astype(jnp.float32) * vs[..., None]
-        k = _expand_kv(k_full.astype(x.dtype), cfg.n_heads)
-        v = _expand_kv(v_full.astype(x.dtype), cfg.n_heads)
-    else:
-        k = _expand_kv(k_rows, cfg.n_heads)
-        v = _expand_kv(v_rows, cfg.n_heads)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
-    s = s * (cfg.hd ** -0.5)
-    valid = jnp.arange(S)[None, None, None, :] <= positions[:, None, :, None]
-    s = jnp.where(valid, s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32)).astype(x.dtype)
+        k = _dequant(k, _table_rows(pks, tables), x.dtype)
+        v = _dequant(v, _table_rows(pvs, tables), x.dtype)
+    valid = jnp.arange(S)[None, None, :] <= positions[:, :, None]
+    o = _cached_attend(q, k, v, valid, cfg.hd).astype(x.dtype)
     out = L.pdot(o.reshape(B, W, cfg.n_heads * cfg.hd), p["wo"], cfg)
     if int8_cache:
         return out, pool_k, pool_v, pks, pvs
@@ -671,9 +664,9 @@ def paged_decode_attention(
     Bit-identity with the gather-bridge decode is the contract: this path
     gathers exactly one layer's table-addressed rows transiently inside the
     layer body and then computes byte-for-byte the math of
-    :func:`decode_attention` on them (same einsum shapes, same length-S
-    softmax rows, same masks), so native tokens equal bridge tokens equal
-    contiguous tokens (tests/test_serving.py). Rows whose index ran past the
+    :func:`decode_attention` on them (the same :func:`_cached_attend`,
+    length-S softmax rows and masks), so native tokens equal bridge tokens
+    equal contiguous tokens (tests/test_serving.py). Rows whose index ran past the
     slot extent (idle/retired slots) clamp into their zeroed table — the
     reserved null block 0 — mirroring the bridge writeback's clamped null
     write; the null block is never read unmasked.
@@ -721,26 +714,12 @@ def paged_decode_attention(
     else:
         # per-layer transient gather of this layer's table-addressed rows,
         # then exactly decode_attention's math — the bit-identity oracle
-        flat = tables.reshape(-1)
-        k_rows = jnp.take(pool_k, flat, axis=0).reshape(B, S, *pool_k.shape[2:])
-        v_rows = jnp.take(pool_v, flat, axis=0).reshape(B, S, *pool_v.shape[2:])
+        k, v = _table_rows(pool_k, tables), _table_rows(pool_v, tables)
         if int8_cache:
-            ks = jnp.take(pks, flat, axis=0).reshape(B, S, *pks.shape[2:])
-            vs = jnp.take(pvs, flat, axis=0).reshape(B, S, *pvs.shape[2:])
-            k_full = k_rows.astype(jnp.float32) * ks[..., None]
-            v_full = v_rows.astype(jnp.float32) * vs[..., None]
-            k = _expand_kv(k_full.astype(x.dtype), cfg.n_heads)
-            v = _expand_kv(v_full.astype(x.dtype), cfg.n_heads)
-        else:
-            k = _expand_kv(k_rows, cfg.n_heads)
-            v = _expand_kv(v_rows, cfg.n_heads)
-        s = jnp.einsum("bqhd,bkhd->bhqk",
-                       q.astype(jnp.float32), k.astype(jnp.float32))
-        s = s * (cfg.hd ** -0.5)
-        valid = jnp.arange(S)[None, None, None, :] <= index[:, None, None, None]
-        s = jnp.where(valid, s, NEG_INF)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32)).astype(x.dtype)
+            k = _dequant(k, _table_rows(pks, tables), x.dtype)
+            v = _dequant(v, _table_rows(pvs, tables), x.dtype)
+        valid = jnp.arange(S)[None, None, :] <= index[:, None, None]
+        o = _cached_attend(q, k, v, valid, cfg.hd).astype(x.dtype)
     out = L.pdot(o.reshape(B, 1, cfg.n_heads * cfg.hd), p["wo"], cfg)
     if int8_cache:
         return out, pool_k, pool_v, pks, pvs

@@ -1,6 +1,9 @@
 """Attention execution-path variants: chunked vs plain, bf16acc internals,
 SP (query-sharded) attention, int8 KV cache — the §Perf knobs must preserve
-semantics."""
+semantics — and the grouped cache contraction every decode, verify and
+prefill-with-cache path shares."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +12,10 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import forward, init_model, serve
-from repro.models.attention import _chunked_attention, _plain_attention
+from repro.models.attention import (
+    NEG_INF, _cached_attend, _chunked_attention, _dequant, _plain_attention,
+    _quantize_kv,
+)
 
 
 def _setup(arch="tinyllama_1_1b", **overrides):
@@ -83,3 +89,72 @@ def test_long_context_decode_ssm_constant_state():
     c_large = serve.init_cache(cfg, 2, 4096)
     for k in ("mlstm_C", "slstm_c"):
         assert c_small[k].shape == c_large[k].shape  # no seq dimension
+
+
+def _expand_and_upcast(q, k, v, valid, hd):
+    """The cache contraction as it was before the grouped one: K/V repeated
+    to all H heads and upcast to float32 on both sides of both einsums."""
+    rep = q.shape[2] // k.shape[2]
+    k = jnp.repeat(k, rep, axis=2).astype(jnp.float32)
+    v = jnp.repeat(v, rep, axis=2).astype(jnp.float32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k) * (hd ** -0.5)
+    s = jnp.where(valid[:, None], s, NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", w, v)
+
+
+def _ordered_bf16(x):
+    """bf16 bit patterns as integers in the order of the values, so that
+    adjacent bf16 numbers differ by one (across zero too)."""
+    b = np.asarray(x.astype(jnp.bfloat16)).view(np.uint16).astype(np.int32)
+    return np.where(b & 0x8000, -(b & 0x7FFF), b)
+
+
+@pytest.mark.parametrize("sq", [1, 4], ids=["decode", "window"])
+@pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "per_row"])
+@pytest.mark.parametrize("kv_dtype", ["float", "int8"])
+@pytest.mark.parametrize("heads", [(4, 4), (8, 2), (4, 1)],
+                         ids=["mha", "gqa", "mqa"])
+def test_grouped_cache_attend_matches_expand_and_upcast(heads, kv_dtype,
+                                                        per_row, sq):
+    """The grouped contraction reads the cache in its own dtype; the old
+    formula expanded it to H float32 heads. Products of bf16 values are
+    exact in float32 and the probabilities stay float32, so only the order
+    of the float32 sums may differ."""
+    (H, KV), B, S, hd = heads, 3, 40, 16
+    ks = jax.random.split(jax.random.PRNGKey(H + 10 * KV + 100 * sq), 3)
+    q = jax.random.normal(ks[0], (B, sq, H, hd)).astype(jnp.bfloat16)
+    k = (2 * jax.random.normal(ks[1], (B, S, KV, hd))).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, KV, hd)).astype(jnp.bfloat16)
+    if kv_dtype == "int8":
+        k_q, v_q, k_sc, v_sc = _quantize_kv(k, v)
+        k, v = _dequant(k_q, k_sc, jnp.bfloat16), _dequant(v_q, v_sc, jnp.bfloat16)
+    index = jnp.array([0, 17, 36]) if per_row else jnp.array(20)
+    positions = jnp.atleast_1d(index)[:, None] + jnp.arange(sq)
+    valid = jnp.arange(S)[None, None, :] <= positions[:, :, None]
+    new = jax.jit(_cached_attend, static_argnums=4)(q, k, v, valid, hd)
+    ref = jax.jit(_expand_and_upcast, static_argnums=4)(q, k, v, valid, hd)
+    assert new.dtype == ref.dtype == jnp.float32
+    assert new.shape == ref.shape == (B, sq, H, hd)
+    new, ref = np.asarray(new), np.asarray(ref)
+    assert np.abs(new - ref).max() <= 2e-6 * np.abs(ref).max()
+    assert np.abs(_ordered_bf16(new) - _ordered_bf16(ref)).max() <= 1
+
+
+@pytest.mark.parametrize("n_kv", [2, 8], ids=["gqa", "mha"])
+def test_decode_reads_the_cache_unexpanded(n_kv):
+    """Decode, lowered for the TPU (no chip needed), holds cache rows
+    (B, S, *, hd) only as the cache stores them: KV heads in the cache
+    dtype, never H heads or float32. The MHA case, where H == KV, shows the
+    check passes the cache itself. Lowered for the CPU instead, JAX would
+    convert both operands of the mixed-dtype value dot to float32."""
+    cfg = get_config("starcoder2-15b").smoke().replace(n_heads=8, n_kv=n_kv)
+    B, S, hd = 3, 40, cfg.hd
+    params = jax.eval_shape(lambda: init_model(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: serve.init_cache(cfg, B, S,
+                                                    per_slot_index=True))
+    step = jax.jit(lambda p, c, t: serve.decode(p, cfg, c, {"tokens": t}))
+    text = step.trace(params, cache, jax.ShapeDtypeStruct((B, 1), jnp.int32)
+                      ).lower(lowering_platforms=("tpu",)).as_text()
+    rows = set(re.findall(rf"tensor<{B}x{S}x(\d+)x{hd}x(\w+)>", text))
+    assert rows == {(str(n_kv), "bf16")}, rows
