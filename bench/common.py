@@ -5,18 +5,25 @@ one metric is a file of its own under ``bench/``:
 
   configs/<config>.json     sizes as run, source, cuts, departures from the
                             source, deployment, limits
+  families/<family>.py      what the harness knows of a family's shape: its
+                            weight tree with each stack's length, its
+                            fields of the program's ArchConfig, and the
+                            operations a token requires per layer
+                            (contract in ``families/dense.py``)
   references/<family>.py    the plain float32 reference of that family,
                             ``score(c, seed, seqs, n_prompt)``
   mixes/<traffic>.json      traffic parameters for the general generator
   cells/<workload>.json     the cell's offered rate
   metrics/<metric>.py       one reader per metric, ``read(run) -> float|None``
 
-so a later change adds a cell, a mix or a metric as new files plus an entry
-in ``BENCHMARK.json``, and edits nothing here.
+so a later change adds a model family, a configuration, a cell, a mix or a
+metric as new files plus an entry in ``BENCHMARK.json``, and edits nothing
+here.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -62,6 +69,12 @@ def _module(path: Path) -> ModuleType:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@functools.lru_cache(maxsize=None)
+def family(name: str) -> ModuleType:
+    """Loaded once a process: the FLOP counts read it for every token."""
+    return _module(BENCH / "families" / f"{name}.py")
 
 
 def reference(family: str) -> ModuleType:

@@ -15,6 +15,7 @@ from __future__ import annotations
 import sys
 from typing import Dict
 
+from bench import common
 from bench.common import ROOT
 
 if str(ROOT / "src") not in sys.path:
@@ -31,24 +32,13 @@ DECODE_MODULE = "jit_decode_step"
 
 
 def arch(c: Dict, quantize: str = "off") -> ArchConfig:
-    """A configuration file as the program's ArchConfig."""
-    moe = c["family"] == "moe"
+    """A configuration file as the program's ArchConfig: the family's fields
+    (``bench/families/<family>.py`` ``arch``) and the common ones."""
     return ArchConfig(
         name=c["name"], family=c["family"],
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"], n_kv=c["num_key_value_heads"],
-        head_dim=c["head_dim"],
-        d_ff=c["moe_intermediate_size"] if moe else c["intermediate_size"],
-        vocab=c["vocab_size"],
-        n_experts=c.get("n_routed_experts", 0),
-        n_shared_experts=c.get("n_shared_experts", 0),
-        topk=c.get("num_experts_per_tok", 0),
-        act="swiglu" if c["hidden_act"] == "silu" else "gelu",
-        norm=c["norm_type"], rope_kind="rope",
-        rope_theta=float(c["rope_theta"]),
-        tie_embeddings=c["tie_word_embeddings"],
         dtype=c["torch_dtype"], param_dtype=c["torch_dtype"],
-        kv_cache_dtype=c["kv_cache_dtype"], quantize=quantize)
+        kv_cache_dtype=c["kv_cache_dtype"], quantize=quantize,
+        **common.family(c["family"]).arch(c))
 
 
 def router(c: Dict, params, quantize: str = "off") -> Router:

@@ -1,68 +1,48 @@
 """Weights from the seed, made by the benchmark, not by the program.
 
-Every leaf is drawn from its own key: leaf ``i`` of :func:`leaves` (a fixed
-order) takes ``fold_in(seed_key, i)``, and layer ``l`` of a stacked leaf
-takes ``fold_in(leaf_key, l)``. So the serving side builds the whole tree in
-one jitted call on the device, in the dtype it is served in, and the
-reference rebuilds one layer at a time, bit for bit, without holding the
-model in float32.
+Every leaf is drawn from its own key: leaf ``i`` of the family's ``leaves``
+(a fixed order) takes ``fold_in(seed_key, i)``, and layer ``l`` of a
+stacked leaf takes ``fold_in(leaf_key, l)``. So the serving side builds the
+whole tree in one jitted call on the device, in the dtype it is served in,
+and the reference rebuilds one layer at a time, bit for bit, without
+holding the model in float32.
 
-The tree has the layout of the program's dense and MoE families (embed,
-final_ln, lm_head, layers/{ln1, attn, ln2, mlp|moe}); a test checks it
-against the program's own initializer, shape for shape.
+The tree's layout is the family's (``bench/families/<family>.py``): each
+leaf names the stack it is stacked in (its first path key) and that
+stack's length, so a family may hold several stacks of different lengths,
+such as one leading dense layer before its MoE layers. The dense and MoE
+families hold one stack, ``layers``; a test checks their trees against the
+program's own initializer, shape for shape.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from bench.common import seed_key
+from bench import common
 
-# (path, shape without the layer axis, init, stacked); init is a float std,
-# "ones" or "zeros"
-Leaf = Tuple[Tuple[str, ...], Tuple[int, ...], object, bool]
+# (path, shape without the layer axis, init, layers); init is a float std,
+# "ones" or "zeros"; layers is None for a leaf held once, else the length
+# of the stack path[0]
+Leaf = Tuple[Tuple[str, ...], Tuple[int, ...], object, Optional[int]]
 
 
 def leaves(c: Dict) -> List[Leaf]:
     """The leaves of a configuration file's model, in a fixed order."""
-    D, V = c["hidden_size"], c["vocab_size"]
-    Vp = (V + 15) // 16 * 16
-    H, KV, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["head_dim"])
-    ln = (("scale", "ones"), ("bias", "zeros")) if c["norm_type"] == \
-        "layernorm" else (("scale", "ones"),)
-    out: List[Leaf] = [(("embed",), (Vp, D), 0.02, False)]
-    out += [(("final_ln", k), (D,), v, False) for k, v in ln]
-    out.append((("lm_head",), (D, Vp), D ** -0.5, False))
-    for norm in ("ln1", "ln2"):
-        out += [(("layers", norm, k), (D,), v, True) for k, v in ln]
-    for name, shape in (("wq", (D, H * hd)), ("wk", (D, KV * hd)),
-                        ("wv", (D, KV * hd)), ("wo", (H * hd, D))):
-        out.append((("layers", "attn", name), shape, shape[0] ** -0.5, True))
-    if c["family"] == "moe":
-        E, F = c["n_routed_experts"], c["moe_intermediate_size"]
-        Fs = c["n_shared_experts"] * F
-        out.append((("layers", "moe", "router"), (D, E), D ** -0.5, True))
-        for name, shape in (("wi", (E, D, F)), ("wg", (E, D, F)),
-                            ("wo", (E, F, D))):
-            out.append((("layers", "moe", name), shape, shape[-2] ** -0.5,
-                        True))
-        for name, shape in (("wi", (D, Fs)), ("wg", (D, Fs)),
-                            ("wo", (Fs, D))):
-            out.append((("layers", "moe", "shared", name), shape,
-                        shape[0] ** -0.5, True))
-    else:
-        F = c["intermediate_size"]
-        gated = c["hidden_act"] == "silu"
-        names = (("wi", (D, F)), ("wg", (D, F)), ("wo", (F, D))) if gated \
-            else (("wi", (D, F)), ("wo", (F, D)))
-        for name, shape in names:
-            out.append((("layers", "mlp", name), shape, shape[0] ** -0.5,
-                        True))
+    return common.family(c["family"]).leaves(c)
+
+
+def stacks(c: Dict) -> Dict[str, int]:
+    """Each stack's length, in the order of the leaves."""
+    out: Dict[str, int] = {}
+    for path, _, _, n in leaves(c):
+        if n is not None and out.setdefault(path[0], n) != n:
+            raise ValueError(f"stack {path[0]!r} of {c['name']!r} has leaves "
+                             f"of {out[path[0]]} and {n} layers")
     return out
 
 
@@ -84,18 +64,18 @@ def build_fn(c: Dict):
     """The jitted maker of the whole tree from a key, in the configuration's
     ``torch_dtype``. Stacked leaves are drawn layer by layer under
     ``lax.map`` so no float32 copy of a whole stack is ever live."""
-    L = c["num_hidden_layers"]
     dtype = jnp.dtype(c["torch_dtype"])
+    tree_leaves = leaves(c)
 
     def build(key):
         tree: Dict = {}
-        for i, (path, shape, init, stacked) in enumerate(leaves(c)):
+        for i, (path, shape, init, n) in enumerate(tree_leaves):
             k = jax.random.fold_in(key, i)
-            if stacked:
+            if n is not None:
                 v = jax.lax.map(
-                    lambda l, k=k, s=shape, n=init: _draw(
-                        jax.random.fold_in(k, l), s, n, dtype),
-                    jnp.arange(L))
+                    lambda l, k=k, s=shape, std=init: _draw(
+                        jax.random.fold_in(k, l), s, std, dtype),
+                    jnp.arange(n))
             else:
                 v = _draw(k, shape, init, dtype)
             _set(tree, path, v)
@@ -106,22 +86,24 @@ def build_fn(c: Dict):
 
 def model_params(c: Dict, seed: int) -> Dict:
     """The whole tree on the default device, in one jitted call."""
-    return build_fn(c)(seed_key(seed))
+    return build_fn(c)(common.seed_key(seed))
 
 
-def layer_params(c: Dict, seed: int, layer) -> Dict:
-    """One layer's leaves (``layer`` = None: the unstacked ones) exactly as
-    :func:`model_params` draws them, returned in float32."""
-    key = seed_key(seed)
+def layer_params(c: Dict, seed: int, layer, stack: str = "layers") -> Dict:
+    """Layer ``layer`` of ``stack`` (``layer`` = None: the leaves held once)
+    exactly as :func:`model_params` draws them, returned in float32, without
+    the stack's key."""
+    key = common.seed_key(seed)
     tree: Dict = {}
-    for i, (path, shape, init, stacked) in enumerate(leaves(c)):
-        if stacked != (layer is not None):
+    for i, (path, shape, init, n) in enumerate(leaves(c)):
+        if (n is None) != (layer is None) or \
+                (layer is not None and path[0] != stack):
             continue
         k = jax.random.fold_in(key, i)
-        if stacked:
+        if n is not None:
             k = jax.random.fold_in(k, layer)
         v = _draw_f32(k, shape, init, jnp.dtype(c["torch_dtype"]))
-        _set(tree, path[1:] if stacked else path, v)
+        _set(tree, path[1:] if n is not None else path, v)
     return tree
 
 

@@ -53,6 +53,10 @@ def test_every_named_file_is_found():
         assert cfg["name"] == c["name"]
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
         assert (common.BENCH / "references" / f"{cfg['family']}.py").exists()
+        assert (common.BENCH / "families" / f"{cfg['family']}.py").exists()
+        fam = common.family(cfg["family"])
+        for f in ("leaves", "arch", "token_matmul_flops", "attention_flops"):
+            assert callable(getattr(fam, f, None)), (cfg["family"], f)
     for w in bm["workloads"]:
         common.config(w["config"])
         assert common.mix(w["traffic"])["arrivals"] == "poisson"
